@@ -25,20 +25,18 @@ Fidelity rides along as ``read_miss_ratio`` per engine, so the
 traffic-vs-fidelity tradeoff is a measured curve in
 ``BENCH_distributed.json``, not a claim.
 
-The forced-device flag must be set BEFORE jax imports, so the harness
-(``benchmarks.run``) invokes this module through ``run_in_subprocess``; the
-child re-executes ``python -m benchmarks.distributed_bench`` with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
+The sweep runs in the calling process over the devices it sees; shard
+counts past that are emitted as skipped rows.  ``benchmarks.run`` calls
+``bench_distributed`` directly, so on a TPU host one process holds every
+chip.  On a CPU host, set
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before starting
+Python to sweep past 1 device.
 
 Usage: ``PYTHONPATH=src python -m benchmarks.distributed_bench [--quick]``
-(needs the XLA_FLAGS above to sweep past 1 device).
 """
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
 import time
 
 SHARD_COUNTS = (1, 2, 4, 8)
@@ -138,35 +136,6 @@ def bench_distributed(ticks: int = TICKS, n_nodes: int = N_NODES,
     with open(out_path, "w") as f:
         json.dump(results, f, indent=2)
     return results
-
-
-def run_in_subprocess(ticks: int = TICKS, timeout: int = 1800) -> None:
-    """Re-exec the sweep with 8 forced host devices; relay its CSV lines.
-
-    Used by ``benchmarks.run`` — the parent process must keep its own
-    single-device XLA view, and the flag only takes effect before jax
-    initializes.  Failures (timeout, nonzero exit) are reported as skip
-    lines, never raised: a missing device count must not kill the harness.
-    """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    try:
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.distributed_bench",
-             "--ticks", str(ticks)],
-            capture_output=True, text=True, env=env, timeout=timeout,
-        )
-    except subprocess.TimeoutExpired:
-        print(f"distributed.sweep_skipped,0.0,timeout after {timeout}s")
-        return
-    except OSError as e:
-        print(f"distributed.sweep_skipped,0.0,cannot spawn child: {e}")
-        return
-    for line in out.stdout.splitlines():
-        if line and not line.startswith("name,"):
-            print(line)
-    if out.returncode != 0:
-        print(f"distributed.sweep_skipped,0.0,{out.stderr.strip()[-200:]!r}")
 
 
 def main() -> None:

@@ -5,36 +5,41 @@ Emits ``name,us_per_call,derived`` CSV — one section per paper table/figure
 distributed two-engine sweep, and the roofline table when dry-run artifacts
 are present.
 
-Sections are isolated: a bench that cannot run in this environment (most
-commonly because it needs more XLA devices than are visible) prints a
-``<section>.skipped`` line with the reason and the harness moves on, so one
-missing capability never kills the whole run.
+Everything runs in this one process over the visible devices, so on a TPU
+host no child process competes for the chip.  A section that raises prints
+a ``<section>.failed`` line with the reason, the later sections still run,
+and the harness exits nonzero at the end.
 
-Usage: ``PYTHONPATH=src python -m benchmarks.run [--quick]``
+Usage: ``PYTHONPATH=src python -m benchmarks.run [--quick]``.  On a CPU host,
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` lets the distributed
+sweep reach 8 shards.
 """
 from __future__ import annotations
 
 import sys
+import traceback
+
+_FAILED: list[str] = []
 
 
-def _section(name: str, fn, *args, **kwargs) -> None:
-    """Run one bench section; on failure print a skip line, don't crash.
-
-    Device-count problems surface as RuntimeError from mesh construction
-    ("cannot create mesh", "requires N devices") — but any exception is a
-    reason to skip the section, not the harness.
-    """
+def _section(name: str, fn, *args, **kwargs):
+    """Run one bench section; a failure is printed and remembered."""
     try:
         return fn(*args, **kwargs)
-    except Exception as e:  # noqa: BLE001 — harness isolation is the point
+    except Exception as e:  # noqa: BLE001 — reported, then exit nonzero
+        traceback.print_exc()
         reason = f"{type(e).__name__}: {e}"
-        print(f"{name}.skipped,0.0,{reason.splitlines()[0][:160]!r}")
+        print(f"{name}.failed,0.0,{reason.splitlines()[0][:160]!r}")
+        _FAILED.append(name)
         return None
 
 
 def main() -> None:
     quick = "--quick" in sys.argv
     scale = 0.25 if quick else 1.0
+
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import figs
     print("name,us_per_call,derived")
@@ -68,16 +73,17 @@ def main() -> None:
         backend_ticks=0 if quick else 150,
     )
 
-    # Distributed two-engine 1/2/4/8-shard sweep -> BENCH_distributed.json
-    # (subprocess: the forced-device flag must precede jax initialization;
-    # the child itself emits per-row skip lines when devices are missing).
-    from benchmarks.distributed_bench import run_in_subprocess
-    _section("distributed", run_in_subprocess, ticks=int(400 * scale))
+    # Distributed two-engine 1/2/4/8-shard sweep -> BENCH_distributed.json,
+    # over the devices this process sees (rows past that count are skipped).
+    from benchmarks.distributed_bench import bench_distributed
+    _section("distributed", bench_distributed, ticks=int(400 * scale))
 
     from benchmarks.roofline import emit_table
     rows = _section("roofline", emit_table)
     if rows is not None and not rows:
         print("roofline.skipped,0.0,run `python -m repro.launch.dryrun --all` first")
+    if _FAILED:
+        sys.exit(f"failed sections: {', '.join(_FAILED)}")
 
 
 if __name__ == "__main__":

@@ -39,6 +39,7 @@ from repro.core import SCENARIOS, SimConfig, summarize
 from repro.core import backing_store as bs
 from repro.core import workload as wl
 from repro.core.simulator import init_sim, run_any_engine, sim_tick
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _pick_workload(args, ticks: int) -> wl.WorkloadSpec:
@@ -83,6 +84,7 @@ def main() -> None:
                          "bandwidth-lean engine #4 and needs a mutable zipf "
                          "scenario, e.g. --scenario zipf")
     args = ap.parse_args()
+    enable_compile_cache()
 
     ticks = args.minutes * 60
     spec = _pick_workload(args, ticks)

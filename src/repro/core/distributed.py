@@ -272,7 +272,9 @@ def fog_shard_tick(
     nid = jnp.where(at_max, node_ids[:, None], -1)
     win_node = jax.lax.pmax(jnp.max(nid, axis=0), axis)                # (n,)
     is_winner = at_max & (node_ids[:, None] == win_node[None, :])  # ≤1 True globally
-    win_data = jnp.einsum("cq,cqd->qd", is_winner.astype(data_qc.dtype), data_qc)
+    # A select-sum, not a one-hot matmul: a TPU matmul at default precision
+    # rounds f32 payloads to bf16.
+    win_data = jnp.sum(jnp.where(is_winner[:, :, None], data_qc, 0.0), axis=0)
     win_data = jax.lax.psum(win_data, axis)                            # (n, D)
 
     # Responder LRU refresh on this shard.
@@ -474,8 +476,6 @@ def run_distributed_sim(
     trees over psum-reduced counts, and summing counts before the float
     expressions would break the bitwise conformance contract (§8).
     """
-    from jax.experimental.shard_map import shard_map
-
     ndev = mesh.shape[axis]
     assert cfg.n_nodes % ndev == 0, "n_nodes must divide the fog axis"
     wl.validate_run(cfg, ticks)
@@ -502,11 +502,11 @@ def run_distributed_sim(
     )
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(state_spec,),
         out_specs=(state_spec, jax.tree.map(lambda _: repl, TickMetrics.zeros())),
-        check_rep=False,
+        check_vma=False,
     )
     def tick_shard(st):
         return fog_shard_tick(cfg, axis, st)

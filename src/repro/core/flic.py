@@ -115,6 +115,12 @@ def insert(
     )
 
     def wr(field, value):
+        if field.dtype == jnp.bool_:
+            # Scattered as int32 words: on a TPU v5e this vmapped one-line
+            # scatter into the bool ``valid`` table left 922 entries of a
+            # (2000, 50, 4) table wrong, while the int32 tables came out
+            # exact.
+            return wr(field.astype(jnp.int32), value) != 0
         return field.at[sidx, way].set(jnp.where(do_write, value, field[sidx, way]))
 
     cache = CacheState(

@@ -568,8 +568,6 @@ def run_sharded_sim(
     (reads, writes_gen, churn_rejoins), exact global write conservation,
     and epsilon-bounded ratio metrics.
     """
-    from jax.experimental.shard_map import shard_map
-
     validate_sharded(cfg)
     wl.validate_run(cfg, ticks)
     ndev = mesh.shape[axis]
@@ -593,11 +591,11 @@ def run_sharded_sim(
     )
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(state_spec,),
         out_specs=(state_spec, jax.tree.map(lambda _: P(), TickMetrics.zeros())),
-        check_rep=False,
+        check_vma=False,
     )
     def tick_shard(st):
         local = ShardedFogState(

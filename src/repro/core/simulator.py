@@ -851,9 +851,9 @@ def run_any_engine(
         )
     if engine in ("distributed", "sharded"):
         ndev = len(jax.devices())
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        kw = dict(axis_types=(axis_type.Auto,)) if axis_type is not None else {}
-        mesh = jax.make_mesh((ndev,), (axis,), **kw)
+        mesh = jax.make_mesh(
+            (ndev,), (axis,), axis_types=(jax.sharding.AxisType.Auto,)
+        )
         if engine == "sharded":
             # Engine #4 (DESIGN.md §10): bandwidth-lean, tolerance-tier
             # conformance instead of bit-identity.

@@ -11,8 +11,10 @@ the cache-state memory with no per-field scatter traffic.
 
 TPU mapping (DESIGN.md §2/§4): the grid walks node blocks of ``N_BLOCK``
 nodes; each grid step holds its (N_BLOCK, S, W[, D]) table blocks in VMEM
-(~100 KB at simulator scale), copies them input→output once, then each
-node touches exactly its own probed set row via dynamic slices.  Nodes
+and its per-node scalars (key, set index, line ts/origin/dirty, live) in
+an SMEM block, copies the tables input→output once, then each node
+touches exactly its own probed set row via one-row dynamic sublane
+slices; way selects are lane mins over ``where(mask, iota, W)``.  Nodes
 touch disjoint rows, so the sequential node loop has no ordering hazard
 and the pass is bit-identical to the inline ``insert_rows`` scatters and
 the ``kernels/ref.py`` oracle for arbitrary inputs.
@@ -28,12 +30,16 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# Nodes per grid step.  VMEM per step is N_BLOCK * S * W * (6 * 4B + D * 4B)
-# doubled for donation — ~90 KB at the default geometry (S=50, W=4, D=8) —
-# sized for a real (non-interpret) lowering.  The wrapper drops to the
-# largest divisor of N at or under this bound, so no node padding is needed.
+# Nodes per grid step.  VMEM tiles pad the trailing two dims to (8, 128):
+# at the default geometry (S=50, W=4, D=8) a node's seven int tables take
+# 7 x 28 KB and its payload table 200 KB, and the input and output blocks
+# are each double-buffered, so a step holds ~12.7 MB — inside the
+# compiler's VMEM limit on a TPU v5e.  The wrapper drops to the largest
+# divisor of N at or under this bound, so no node padding is needed.
 N_BLOCK = 8
+N_SCALARS = 6  # key, set index, line ts, line origin, line dirty, live
 
 
 def _node_block(n: int) -> int:
@@ -43,13 +49,12 @@ def _node_block(n: int) -> int:
     return 1
 
 
-def _kernel(keys_ref, sidx_ref, line_ts_ref, line_origin_ref, line_dirty_ref,
-            live_ref, now_ref,
+def _kernel(now_ref, scal_ref,
             tags_in, ts_in, ins_in, org_in, val_in, dir_in, lu_in,
             line_data_ref, data_in,
             tags_out, ts_out, ins_out, org_out, val_out, dir_out, lu_out,
             data_out):
-    nb = keys_ref.shape[0]
+    nb = tags_in.shape[0]
     w = tags_in.shape[-1]
 
     # Copy this node block input -> output (identity under donation), then
@@ -68,51 +73,53 @@ def _kernel(keys_ref, sidx_ref, line_ts_ref, line_origin_ref, line_dirty_ref,
     int_max = jnp.iinfo(jnp.int32).max
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)          # (1, W)
 
+    def first(mask):  # first True lane as (1, 1); W when none (argmax-free)
+        return jnp.min(jnp.where(mask, lane, w), axis=1, keepdims=True)
+
     def body(j, _):
-        key = keys_ref[j]
-        s = sidx_ref[j]
-        lts = line_ts_ref[j]
-        lorg = line_origin_ref[j]
-        ldir = line_dirty_ref[j]
-        lv = live_ref[j] != 0
-        idx = (pl.ds(j, 1), pl.ds(s, 1), slice(None))
-        row_tags = pl.load(tags_out, idx)[0]                       # (1, W)
-        row_ts = pl.load(ts_out, idx)[0]
-        row_ins = pl.load(ins_out, idx)[0]
-        row_org = pl.load(org_out, idx)[0]
-        row_val = pl.load(val_out, idx)[0]
-        row_dir = pl.load(dir_out, idx)[0]
-        row_use = pl.load(lu_out, idx)[0]
+        key = scal_ref[j, 0, 0]
+        s = scal_ref[j, 0, 1]
+        lts = scal_ref[j, 0, 2]
+        lorg = scal_ref[j, 0, 3]
+        ldir = scal_ref[j, 0, 4]
+        lv = scal_ref[j, 0, 5] != 0
+        row = (j, pl.ds(s, 1), slice(None))
+        row_tags = tags_out[row]                                   # (1, W)
+        row_ts = ts_out[row]
+        row_ins = ins_out[row]
+        row_org = org_out[row]
+        row_val = val_out[row]
+        row_dir = dir_out[row]
+        row_use = lu_out[row]
 
         valid = row_val != 0
         match = valid & (row_tags == key)
-        present = jnp.any(match)
-        present_way = jnp.argmax(match, axis=1)                    # first way
-        any_inv = jnp.any(~valid)
-        inv_way = jnp.argmax(~valid, axis=1)                       # first invalid
+        present = jnp.any(match, axis=1, keepdims=True)            # (1, 1)
+        present_way = first(match)                                 # first way
+        any_inv = jnp.any(~valid, axis=1, keepdims=True)
+        inv_way = first(~valid)                                    # first invalid
         use = jnp.where(valid, row_use, int_max)
-        lru_way = jnp.argmin(use, axis=1)
+        lru_way = first(use == jnp.min(use, axis=1, keepdims=True))
         victim = jnp.where(any_inv, inv_way, lru_way)
-        way = jnp.where(present, present_way, victim)              # (1,)
+        way = jnp.where(present, present_way, victim)              # (1, 1)
 
-        sel = lane == way[:, None]                                 # (1, W)
-        old_ts = jnp.sum(jnp.where(sel, row_ts, 0))                # one-hot pick
+        sel = lane == way                                          # (1, W)
+        old_ts = jnp.sum(jnp.where(sel, row_ts, 0), axis=1, keepdims=True)
         stale = present & (lts <= old_ts)
         wr = sel & (lv & ~stale)                                   # (1, W)
 
-        pl.store(tags_out, idx, jnp.where(wr, key, row_tags)[None])
-        pl.store(ts_out, idx, jnp.where(wr, lts, row_ts)[None])
-        pl.store(ins_out, idx, jnp.where(wr, now, row_ins)[None])
-        pl.store(org_out, idx, jnp.where(wr, lorg, row_org)[None])
-        pl.store(val_out, idx, jnp.where(wr, 1, row_val)[None])
-        pl.store(dir_out, idx, jnp.where(wr, ldir, row_dir)[None])
-        pl.store(lu_out, idx, jnp.where(wr, now, row_use)[None])
+        tags_out[row] = jnp.where(wr, key, row_tags)
+        ts_out[row] = jnp.where(wr, lts, row_ts)
+        ins_out[row] = jnp.where(wr, now, row_ins)
+        org_out[row] = jnp.where(wr, lorg, row_org)
+        val_out[row] = jnp.where(wr, 1, row_val)
+        dir_out[row] = jnp.where(wr, ldir, row_dir)
+        lu_out[row] = jnp.where(wr, now, row_use)
 
-        didx = (pl.ds(j, 1), pl.ds(s, 1), slice(None), slice(None))
-        row_data = pl.load(data_out, didx)[0]                      # (1, W, D)
-        ld = line_data_ref[j, :]                                   # (D,)
-        pl.store(data_out, didx,
-                 jnp.where(wr[:, :, None], ld[None, None, :], row_data)[None])
+        ld = line_data_ref[j]                                      # (1, D)
+        for k in range(w):
+            cur = data_out[j, s, pl.ds(k, 1), :]                   # (1, D)
+            data_out[j, s, pl.ds(k, 1), :] = jnp.where(wr[:, k:k + 1], ld, cur)
         return 0
 
     jax.lax.fori_loop(0, nb, body, 0)
@@ -143,31 +150,34 @@ def flic_insert_pallas(
     nb = _node_block(n)
     grid = (n // nb,)
 
-    nodewise = pl.BlockSpec((nb,), lambda i: (i,))
-    tab = pl.BlockSpec((nb, s, w), lambda i: (i, 0, 0))
-    tab3 = pl.BlockSpec((nb, s, w, d), lambda i: (i, 0, 0, 0))
+    tab = pl.BlockSpec((nb, s, w), lambda i, *_: (i, 0, 0))
+    tab3 = pl.BlockSpec((nb, s, w, d), lambda i, *_: (i, 0, 0, 0))
+    # The per-node scalars travel packed as (N, 1, 6) so a node block is an
+    # SMEM block whose trailing two dims are whole (no 128-lane rank-1 rule).
+    scal = jnp.stack([
+        keys, sidx, line_ts, line_origin,
+        line_dirty.astype(jnp.int32), live.astype(jnp.int32),
+    ], axis=-1).astype(jnp.int32)[:, None, :]
     out = pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            nodewise,                               # keys
-            nodewise,                               # sidx
-            nodewise,                               # line_ts
-            nodewise,                               # line_origin
-            nodewise,                               # line_dirty
-            nodewise,                               # live
-            pl.BlockSpec((1,), lambda i: (0,)),     # now
-            tab,                                    # tags      (donated)
-            tab,                                    # data_ts   (donated)
-            tab,                                    # ins_ts    (donated)
-            tab,                                    # origin    (donated)
-            tab,                                    # valid     (donated)
-            tab,                                    # dirty     (donated)
-            tab,                                    # last_use  (donated)
-            pl.BlockSpec((nb, d), lambda i: (i, 0)),  # line_data
-            tab3,                                   # data      (donated)
-        ],
-        out_specs=[tab, tab, tab, tab, tab, tab, tab, tab3],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                  # now -> SMEM
+            grid=(n // nb,),
+            in_specs=[
+                pl.BlockSpec((nb, 1, N_SCALARS), lambda i, *_: (i, 0, 0),
+                             memory_space=pltpu.SMEM),  # per-node scalars
+                tab,                                # tags      (donated)
+                tab,                                # data_ts   (donated)
+                tab,                                # ins_ts    (donated)
+                tab,                                # origin    (donated)
+                tab,                                # valid     (donated)
+                tab,                                # dirty     (donated)
+                tab,                                # last_use  (donated)
+                pl.BlockSpec((nb, 1, d), lambda i, *_: (i, 0, 0)),  # line_data
+                tab3,                               # data      (donated)
+            ],
+            out_specs=[tab, tab, tab, tab, tab, tab, tab, tab3],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((n, s, w), jnp.int32),   # tags
             jax.ShapeDtypeStruct((n, s, w), jnp.int32),   # data_ts
@@ -178,17 +188,13 @@ def flic_insert_pallas(
             jax.ShapeDtypeStruct((n, s, w), jnp.int32),   # last_use
             jax.ShapeDtypeStruct((n, s, w, d), data.dtype),
         ],
-        input_output_aliases={
-            7: 0, 8: 1, 9: 2, 10: 3, 11: 4, 12: 5, 13: 6, 15: 7,
-        },
+        input_output_aliases={2: 0, 3: 1, 4: 2, 5: 3, 6: 4, 7: 5, 8: 6, 10: 7},
         interpret=interpret,
     )(
-        keys, sidx, line_ts, line_origin,
-        line_dirty.astype(jnp.int32), live.astype(jnp.int32),
-        jnp.full((1,), jnp.asarray(now, jnp.int32)),
+        jnp.full((1,), jnp.asarray(now, jnp.int32)), scal,
         tags, data_ts, ins_ts, origin,
         valid.astype(jnp.int32), dirty.astype(jnp.int32), last_use,
-        line_data, data,
+        line_data[:, None, :], data,
     )
     (n_tags, n_ts, n_ins, n_org, n_val, n_dir, n_lu, n_data) = out
     return (n_tags, n_ts, n_ins, n_org, n_val.astype(bool),

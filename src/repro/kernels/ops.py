@@ -8,7 +8,9 @@
 
 Default: interpret on CPU hosts, pallas on TPU.  ``REPRO_KERNELS=xla``
 forces the oracle (used by the serving engine's dry-run lowering, since a
-TPU kernel cannot lower on the CPU AOT path).
+TPU kernel cannot lower on the CPU AOT path).  ``REPRO_KERNELS=interpret``
+is refused on a TPU, so a chip run never goes through the interpreter
+unseen.
 """
 from __future__ import annotations
 
@@ -29,11 +31,17 @@ from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
 def _mode() -> str:
+    on_tpu = jax.default_backend() == "tpu"
     env = os.environ.get("REPRO_KERNELS")
+    if env == "interpret" and on_tpu:
+        raise RuntimeError(
+            "REPRO_KERNELS=interpret on a TPU would run every kernel in the "
+            "Pallas interpreter; unset it (kernels then lower for the chip) "
+            "or pick backend='interpret' explicitly"
+        )
     if env:
         return env
-    platform = jax.devices()[0].platform
-    return "pallas" if platform == "tpu" else "interpret"
+    return "pallas" if on_tpu else "interpret"
 
 
 def flic_lookup(tags, data_ts, valid, data, keys, sidx, backend: str | None = None):

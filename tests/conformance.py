@@ -241,13 +241,21 @@ def sharded_case_report(name: str, seed: int) -> dict:
     Raises AssertionError on any violated bound; returns
     ``{"sharded": summary, "fused": summary}``.
     """
-    import jax
-
-    tol = SHARDED_CASES[name]
     _, s_series = run_case(name, seed, "sharded")
     _, f_series = run_case(name, seed, "fused")
     ss, fs = summarize(s_series), summarize(f_series)
-    label = f"sharded:{name}/seed{seed}"
+    assert_sharded_tolerance(
+        SHARDED_CASES[name], ss, fs, f"sharded:{name}/seed{seed}"
+    )
+    return {"sharded": ss, "fused": fs}
+
+
+def assert_sharded_tolerance(tol: ShardedTolerance, ss: dict, fs: dict,
+                             label: str) -> None:
+    """The tolerance-tier contract between a ``sharded`` summary ``ss`` and
+    the bit-exact ``fused`` summary ``fs`` of the same config and seed."""
+    import jax
+
     # Deterministic plan quantities are exact.
     for field in ("ticks", "reads", "writes_gen", "churn_rejoins"):
         assert ss[field] == fs[field], (
@@ -280,7 +288,6 @@ def sharded_case_report(name: str, seed: int) -> dict:
         assert ss["wire_bytes_per_tick"] > 0, (
             f"{label}: multi-shard run reported zero on-wire bytes"
         )
-    return {"sharded": ss, "fused": fs}
 
 
 def main(argv=None) -> None:

@@ -87,14 +87,10 @@ class TestRooflineModel:
 class TestPlans:
     @pytest.fixture
     def mesh(self):
-        # ``axis_types`` / ``jax.sharding.AxisType`` only exist on newer JAX;
-        # the default (Auto on every axis) is what we want anyway.
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:
-            return jax.make_mesh(
-                (1, 1), ("data", "model"), axis_types=(axis_type.Auto,) * 2
-            )
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return jax.make_mesh(
+            (1, 1), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        )
 
     def test_every_plan_resolves_every_axis(self, mesh):
         from repro.models.model import model_axes

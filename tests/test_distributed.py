@@ -18,9 +18,8 @@ def test_distributed_fog_matches_headline(forced_devices_run):
         import jax, json
         from repro.core import SimConfig, summarize
         from repro.core.distributed import run_distributed_sim
-        AxisType = getattr(jax.sharding, 'AxisType', None)
-        kw = dict(axis_types=(AxisType.Auto,)) if AxisType else {}
-        mesh = jax.make_mesh((8,), ('data',), **kw)
+        mesh = jax.make_mesh((8,), ('data',),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         cfg = SimConfig(n_nodes=48, cache_lines=200, loss_prob=0.01)
         _, series = run_distributed_sim(mesh, cfg, 500, axis='data')
         s = summarize(series)
@@ -44,9 +43,8 @@ def test_distributed_fog_runs_workload_scenarios(forced_devices_run):
         from repro.core.workload import WorkloadSpec
         from repro.core.distributed import run_distributed_sim
         from repro.core.writeback import ring_accounting
-        AxisType = getattr(jax.sharding, 'AxisType', None)
-        kw = dict(axis_types=(AxisType.Auto,)) if AxisType else {}
-        mesh = jax.make_mesh((8,), ('data',), **kw)
+        mesh = jax.make_mesh((8,), ('data',),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         spec = WorkloadSpec(popularity='zipf', key_universe=1024, zipf_alpha=1.1,
                             churn_period=100, churn_fraction=0.25)
         cfg = SimConfig(n_nodes=48, cache_lines=200, loss_prob=0.01, workload=spec)
@@ -160,9 +158,8 @@ def test_mini_dryrun_lowers_and_compiles(forced_devices_run):
         from repro.config import get_arch, SHAPES
         from repro.launch.specs import build_cell
         from repro.shard.partition import use_rules, PLANS
-        AxisType = getattr(jax.sharding, 'AxisType', None)
-        kw = dict(axis_types=(AxisType.Auto, AxisType.Auto)) if AxisType else {}
-        mesh = jax.make_mesh((2, 4), ('data', 'model'), **kw)
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = get_arch('granite_8b')
         cell = build_cell(cfg, SHAPES['decode_32k'], mesh)
         with mesh, use_rules(mesh, 'decode'):
