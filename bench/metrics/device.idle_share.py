@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of the device-operation intervals) / window, in percent."""
+
+
+def read(run):
+    ts = run.trace_summary
+    if ts is None or ts.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - ts.busy_s / ts.window_s)
