@@ -1,0 +1,9 @@
+"""Device time of the fused tick's ops in the coherence sweep (``stage.sweep``:
+the delivery mask and ``flic.update_rows``), by exclusive op time, per
+simulated tick over the chunks of the traced window. None where the stage
+owns no op."""
+from harness.stages import stage_ms_per_tick
+
+
+def read(run):
+    return stage_ms_per_tick(run, "sweep")

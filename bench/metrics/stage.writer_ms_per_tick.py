@@ -1,0 +1,9 @@
+"""Device time of the fused tick's ops in the writer stage (``stage.writer``:
+enqueue, the backstop resolve, drain and commit), by exclusive op time, per
+simulated tick over the chunks of the traced window. None where the stage
+owns no op."""
+from harness.stages import stage_ms_per_tick
+
+
+def read(run):
+    return stage_ms_per_tick(run, "writer")

@@ -1,9 +1,8 @@
 """Benchmark harness entry point.
 
 Emits ``name,us_per_call,derived`` CSV — one section per paper table/figure
-(Figs. 2-5 + abstract claims + §II-B bound), kernel microbenchmarks, the
-distributed two-engine sweep, and the roofline table when dry-run artifacts
-are present.
+(Figs. 2-5 + abstract claims + §II-B bound), kernel microbenchmarks, and
+the distributed two-engine sweep.
 
 Everything runs in this one process over the visible devices, so on a TPU
 host no child process competes for the chip.  A section that raises prints
@@ -78,10 +77,6 @@ def main() -> None:
     from benchmarks.distributed_bench import bench_distributed
     _section("distributed", bench_distributed, ticks=int(400 * scale))
 
-    from benchmarks.roofline import emit_table
-    rows = _section("roofline", emit_table)
-    if rows is not None and not rows:
-        print("roofline.skipped,0.0,run `python -m repro.launch.dryrun --all` first")
     if _FAILED:
         sys.exit(f"failed sections: {', '.join(_FAILED)}")
 

@@ -435,36 +435,48 @@ def _probe_all_caches(cfg: SimConfig, caches: CacheState, keys_q, sidx_q):
 
 # --------------------------------------------------------------------------
 # One tick (fused engine).
+#
+# Each section runs under a named stage (plan, sweep, upsert, probe,
+# writer): the scope lands in the ``op_name`` metadata of every HLO op it
+# lowers to, so a device trace of the scan can be reduced per stage.  It is
+# metadata only: compiled without it, the program has the same instructions
+# (a few carry other numbers in their names).  Section 6's scalar
+# accounting stays outside every stage.
 # --------------------------------------------------------------------------
+
+def _stage(name: str):
+    return jax.named_scope(f"stage.{name}")
+
 
 def sim_tick(cfg: SimConfig, state: SimState, _=None) -> tuple[SimState, TickMetrics]:
     n = cfg.n_nodes
     spec = cfg.workload
     t = state.tick
-    # The plan stage: ALL request generation (writes, reads, masks, slots,
-    # the tick's PRNG split) happens in workload.plan_tick; this engine only
-    # executes the returned tensors.
-    plan = wl.plan_tick(cfg, state.plan, t, state.rng)
-    m = TickMetrics.zeros()
-    caches = state.caches
-    latest_ts = state.latest_ts
-    store_in = state.store
-    if cfg.outage_schedule:
-        store_in = bs.apply_outage_schedule(store_in, t, cfg.outage_schedule)
+    with _stage("plan"):
+        # The plan stage: ALL request generation (writes, reads, masks,
+        # slots, the tick's PRNG split) happens in workload.plan_tick; this
+        # engine only executes the returned tensors.
+        plan = wl.plan_tick(cfg, state.plan, t, state.rng)
+        m = TickMetrics.zeros()
+        caches = state.caches
+        latest_ts = state.latest_ts
+        store_in = state.store
+        if cfg.outage_schedule:
+            store_in = bs.apply_outage_schedule(store_in, t, cfg.outage_schedule)
 
-    # ---- 0. churn: rejoining nodes cold-start -----------------------------
-    online = plan.online
-    if spec.has_churn:
-        caches = invalidate_nodes(caches, plan.rejoin)
-        n_rejoin = jnp.sum(plan.rejoin.astype(jnp.int32))
-    else:
-        n_rejoin = jnp.int32(0)
+        # ---- 0. churn: rejoining nodes cold-start -------------------------
+        online = plan.online
+        if spec.has_churn:
+            caches = invalidate_nodes(caches, plan.rejoin)
+            n_rejoin = jnp.sum(plan.rejoin.astype(jnp.int32))
+        else:
+            n_rejoin = jnp.int32(0)
 
-    # ---- 1. materialize the plan's write waves ----------------------------
-    rows_waves = [
-        wl.plan_write_rows(cfg, plan, p, t) for p in range(spec.plan_waves)
-    ]
-    n_writes = jnp.sum(plan.w_valid.astype(jnp.int32))
+        # ---- 1. materialize the plan's write waves ------------------------
+        rows_waves = [
+            wl.plan_write_rows(cfg, plan, p, t) for p in range(spec.plan_waves)
+        ]
+        n_writes = jnp.sum(plan.w_valid.astype(jnp.int32))
     m = dataclasses.replace(m, writes_gen=n_writes)
 
     # ---- 2. fog broadcast under the loss model ----------------------------
@@ -472,261 +484,272 @@ def sim_tick(cfg: SimConfig, state: SimState, _=None) -> tuple[SimState, TickMet
     # mask is drawn only when the sweep/merge consumes it, K-compact under
     # fanout.
     nbr = _neighbor_index(cfg)
-    channel, k_dmask = _advance_channel(cfg, state.channel, plan.k_deliver)
-    if _needs_delivery_mask(cfg):
-        delivered = _delivery_mask_dense(cfg, channel, k_dmask, nbr)
-        if spec.has_churn:
-            delivered = delivered & online[:, None]  # offline nodes hear nothing
-    else:
-        delivered = None  # write-once directory: provably unused
+    with _stage("plan"):
+        channel, k_dmask = _advance_channel(cfg, state.channel, plan.k_deliver)
+    with _stage("sweep"):
+        if _needs_delivery_mask(cfg):
+            delivered = _delivery_mask_dense(cfg, channel, k_dmask, nbr)
+            if spec.has_churn:
+                delivered = delivered & online[:, None]  # offline nodes hear nothing
+        else:
+            delivered = None  # write-once directory: provably unused
     n_coh = jnp.int32(0)
     if cfg.insert_policy == "directory":
         for rows in rows_waves:
             # Origin-resident payload via ONE batched upsert per wave.
-            caches, _ev = insert_rows(caches, rows, t, backend=cfg.probe_backend)
+            with _stage("upsert"):
+                caches, _ev = insert_rows(caches, rows, t, backend=cfg.probe_backend)
             if spec.mutable:
                 # The scenario can re-write keys: run the LIVE batched
                 # coherence sweep (hearers update resident older copies in
                 # place).  The sweep dispatches through the same
                 # kernel-backend knob as the fog probe (inline winr
                 # election, or kernels.ops.flic_update).
-                caches, n_coh_p = update_rows(
-                    caches, rows, delivered, t, backend=cfg.probe_backend
-                )
+                with _stage("sweep"):
+                    caches, n_coh_p = update_rows(
+                        caches, rows, delivered, t, backend=cfg.probe_backend
+                    )
                 n_coh = n_coh + n_coh_p
             # else: write-once keys — the sweep is a provable no-op and is
             # skipped (see flic.update_rows; equivalence is asserted against
             # the reference engine which still runs it).
     else:
         for rows in rows_waves:
-            caches = _merge_replicate(caches, rows, delivered, t)
+            with _stage("upsert"):
+                caches = _merge_replicate(caches, rows, delivered, t)
     lan = n_writes.astype(jnp.float32) * cfg.row_bytes  # broadcasts on the medium
 
     # ---- 3. write-behind enqueue (single writer, §I.A.b) ------------------
     queue = state.queue
-    if spec.mutable:
-        for p, rows in enumerate(rows_waves):
-            queue, _acc = wb.enqueue_keyed(
-                queue, plan.w_kids[p], rows.data_ts, rows.origin, plan.w_valid[p]
+    with _stage("writer"):
+        if spec.mutable:
+            for p, rows in enumerate(rows_waves):
+                queue, _acc = wb.enqueue_keyed(
+                    queue, plan.w_kids[p], rows.data_ts, rows.origin, plan.w_valid[p]
+                )
+                latest_ts = latest_ts.at[
+                    jnp.where(plan.w_valid[p], plan.w_kids[p], spec.key_universe)
+                ].max(rows.data_ts, mode="drop")
+        else:
+            rows = rows_waves[0]
+            queue, _acc = wb.enqueue(
+                queue, rows.key, rows.data_ts, rows.origin, plan.w_valid[0]
             )
-            latest_ts = latest_ts.at[
-                jnp.where(plan.w_valid[p], plan.w_kids[p], spec.key_universe)
-            ].max(rows.data_ts, mode="drop")
-    else:
-        rows = rows_waves[0]
-        queue, _acc = wb.enqueue(
-            queue, rows.key, rows.data_ts, rows.origin, plan.w_valid[0]
-        )
 
     # ---- 4. reads: execute the plan's read lanes --------------------------
     reading = plan.reading
     r_keys = plan.r_keys
+    with _stage("probe"):
+        # Reader compaction: the plan's (R,) slot tensors (for the staggered
+        # schedule, the arithmetic progression node ≡ -t (mod read_period) with
+        # static R = ceil(N / read_period); for trace replay, R = N).  The
+        # fused probe touches (C, R, W) instead of the seed's (C, N, W).
+        r_slots = plan.slot_ok.shape[0]
+        r_ids = plan.slot_id                                           # (R,)
+        slot_ok = plan.slot_ok
+        r_gidx = plan.slot_nid                                         # safe gather
+        keys_q = r_keys[r_gidx]
+        sidx_q = (keys_q % jnp.uint32(cfg.cache_sets)).astype(jnp.int32)
 
-    # Reader compaction: the plan's (R,) slot tensors (for the staggered
-    # schedule, the arithmetic progression node ≡ -t (mod read_period) with
-    # static R = ceil(N / read_period); for trace replay, R = N).  The
-    # fused probe touches (C, R, W) instead of the seed's (C, N, W).
-    r_slots = plan.slot_ok.shape[0]
-    r_ids = plan.slot_id                                           # (R,)
-    slot_ok = plan.slot_ok
-    r_gidx = plan.slot_nid                                         # safe gather
-    keys_q = r_keys[r_gidx]
-    sidx_q = (keys_q % jnp.uint32(cfg.cache_sets)).astype(jnp.int32)
+        slots = jnp.arange(r_slots)
+        if nbr is None:
+            # 4a+4b fused (dense): ONE probe of the R queries against all C
+            # caches serves the reader's local check (its own lane), the fog
+            # broadcast query, and the LRU-touch scatter.
+            hit_cq, way_cq, ts_cq, payload_of = _probe_all_caches(
+                cfg, caches, keys_q, sidx_q
+            )
 
-    slots = jnp.arange(r_slots)
-    if nbr is None:
-        # 4a+4b fused (dense): ONE probe of the R queries against all C
-        # caches serves the reader's local check (its own lane), the fog
-        # broadcast query, and the LRU-touch scatter.
-        hit_cq, way_cq, ts_cq, payload_of = _probe_all_caches(
-            cfg, caches, keys_q, sidx_q
+            hit_local_slot = hit_cq[r_gidx, slots] & slot_ok           # (R,)
+            need_fog_slot = slot_ok & ~hit_local_slot
+            ts_local_slot = ts_cq[r_gidx, slots]
+
+            # Response loss: each responder's reply may be lost independently.
+            # The draw covers only the R reader-compaction rows (DESIGN.md §9).
+            hit_fog_cq = hit_cq
+            resp_rq = _response_mask_compact(cfg, channel, plan.k_resp, r_gidx, nbr)
+            if resp_rq is not None:
+                hit_fog_cq = hit_fog_cq & resp_rq.T                    # (C, R)
+            if spec.has_churn:
+                hit_fog_cq = hit_fog_cq & online[:, None]              # silent offline
+            hit_fog_cq = hit_fog_cq & need_fog_slot[None, :]
+            ts_fog = jnp.where(hit_fog_cq, ts_cq, -1)
+
+            best_c = jnp.argmax(ts_fog, axis=0)                    # (R,) ties → lowest node id
+            fog_hit_slot = jnp.any(hit_fog_cq, axis=0)
+            best_ts_slot = jnp.where(fog_hit_slot, ts_fog[best_c, slots], -1)
+            best_payload_slot = payload_of(best_c, slots)              # (R, D)
+
+            # LRU refresh in ONE scatter: the reader's local hit plus every
+            # responder that served a query.  The scatter-max runs along the
+            # SHARED query set-index vector (R slice-updates, each vectorized
+            # over all C caches) with the per-cache way variability moved into
+            # the VALUES — XLA serializes per-element (C, R)-indexed scatters
+            # on CPU.
+            touch_cq = hit_fog_cq.at[r_gidx, slots].max(hit_local_slot)
+            touch_w = touch_cq[:, :, None] & (
+                jax.lax.iota(jnp.int32, cfg.cache_ways)[None, None, :]
+                == way_cq[:, :, None]
+            )
+            caches = dataclasses.replace(
+                caches,
+                last_use=caches.last_use.at[:, sidx_q].max(jnp.where(touch_w, t, -1)),
+            )
+
+            n_responses = jnp.sum(hit_fog_cq.astype(jnp.int32))
+        else:
+            # 4a+4b fused (fanout): the reader probes ONLY itself plus its K
+            # ring neighbors — (R, K+1) lanes, lane 0 local — so the probe,
+            # response loss, winner election, payload gather and LRU touch are
+            # all O(R·K), never O(N²).  Ties break by lane (nearest ring
+            # offset) instead of lowest node id: unobservable, because
+            # same-(key, ts) payloads are value-identical by construction.
+            cols = jnp.concatenate([r_gidx[:, None], nbr[r_gidx]], axis=1)
+            tags_l = caches.tags[cols, sidx_q[:, None]]                # (R, K+1, W)
+            valid_l = caches.valid[cols, sidx_q[:, None]]
+            match_l = valid_l & (tags_l == keys_q[:, None, None])
+            hit_l = jnp.any(match_l, axis=-1)                          # (R, K+1)
+            way_l = jnp.argmax(match_l, axis=-1).astype(jnp.int32)     # first-way wins
+            ts_raw_l = jnp.take_along_axis(
+                caches.data_ts[cols, sidx_q[:, None]], way_l[..., None], axis=-1
+            )[..., 0]
+
+            hit_local_slot = hit_l[:, 0] & slot_ok                     # (R,)
+            need_fog_slot = slot_ok & ~hit_local_slot
+            ts_local_slot = jnp.where(hit_l[:, 0], ts_raw_l[:, 0], -1)
+
+            hit_fog_l = hit_l[:, 1:]                                   # (R, K)
+            resp_l = _response_mask_compact(cfg, channel, plan.k_resp, r_gidx, nbr)
+            if resp_l is not None:
+                hit_fog_l = hit_fog_l & resp_l
+            if spec.has_churn:
+                hit_fog_l = hit_fog_l & online[cols[:, 1:]]            # silent offline
+            hit_fog_l = hit_fog_l & need_fog_slot[:, None]
+            ts_fog_l = jnp.where(hit_fog_l, ts_raw_l[:, 1:], -1)
+
+            best_lane = jnp.argmax(ts_fog_l, axis=1)                   # (R,)
+            fog_hit_slot = jnp.any(hit_fog_l, axis=1)
+            best_ts_slot = jnp.where(fog_hit_slot, ts_fog_l[slots, best_lane], -1)
+            best_payload_slot = caches.data[
+                cols[slots, 1 + best_lane], sidx_q, way_l[slots, 1 + best_lane]
+            ]                                                          # (R, D)
+
+            # LRU refresh: flat scatter-max over the touched (cache, set, way)
+            # cells — O(R·K) updates, duplicates merge under max.
+            touch_l = jnp.concatenate([hit_local_slot[:, None], hit_fog_l], axis=1)
+            flat = (cols * cfg.cache_sets + sidx_q[:, None]) * cfg.cache_ways + way_l
+            oob = n * cfg.cache_sets * cfg.cache_ways
+            flat = jnp.where(touch_l, flat, oob)
+            caches = dataclasses.replace(
+                caches,
+                last_use=caches.last_use.reshape(-1)
+                .at[flat.reshape(-1)].max(t, mode="drop")
+                .reshape(caches.last_use.shape),
+            )
+
+            n_responses = jnp.sum(hit_fog_l.astype(jnp.int32))
+
+        n_fog_queries = jnp.sum(need_fog_slot.astype(jnp.int32))
+
+    with _stage("writer"):
+        # 4c. writer-buffer forwarding, then the backing store (§VI).
+        healthy = bs.store_healthy(store_in, t)
+        need_store_slot = need_fog_slot & ~fog_hit_slot
+        if spec.mutable:
+            kids_q = plan.r_kids[r_gidx]
+            (queue_hit_slot, store_read_slot, failed_slot, found_slot,
+             served_ts_slot) = _resolve_backstop_keyed(
+                queue, store_in, healthy, need_store_slot, kids_q
+            )
+        else:
+            enq_idx_slot = plan.r_enq_idx[r_gidx]
+            queue_hit_slot, store_read_slot, failed_slot, found_slot, _ = _resolve_backstop(
+                queue, store_in, healthy, need_store_slot, enq_idx_slot
+            )
+        n_store_reads = jnp.sum(store_read_slot.astype(jnp.int32))
+        n_queue_hits = jnp.sum(queue_hit_slot.astype(jnp.int32))
+        n_failed = jnp.sum(failed_slot.astype(jnp.int32))
+        lan = (
+            lan + n_fog_queries * cfg.query_bytes
+            + (n_responses + n_queue_hits) * cfg.row_bytes
+        )
+        txn = cfg.store.read_txn_bytes(store_in.drained_total)
+        wan_rx = n_store_reads.astype(jnp.float32) * txn
+        store = dataclasses.replace(
+            store_in, api_calls=store_in.api_calls + n_store_reads
         )
 
-        hit_local_slot = hit_cq[r_gidx, slots] & slot_ok           # (R,)
-        need_fog_slot = slot_ok & ~hit_local_slot
-        ts_local_slot = ts_cq[r_gidx, slots]
-
-        # Response loss: each responder's reply may be lost independently.
-        # The draw covers only the R reader-compaction rows (DESIGN.md §9).
-        hit_fog_cq = hit_cq
-        resp_rq = _response_mask_compact(cfg, channel, plan.k_resp, r_gidx, nbr)
-        if resp_rq is not None:
-            hit_fog_cq = hit_fog_cq & resp_rq.T                    # (C, R)
-        if spec.has_churn:
-            hit_fog_cq = hit_fog_cq & online[:, None]              # silent offline
-        hit_fog_cq = hit_fog_cq & need_fog_slot[None, :]
-        ts_fog = jnp.where(hit_fog_cq, ts_cq, -1)
-
-        best_c = jnp.argmax(ts_fog, axis=0)                        # (R,) ties → lowest node id
-        fog_hit_slot = jnp.any(hit_fog_cq, axis=0)
-        best_ts_slot = jnp.where(fog_hit_slot, ts_fog[best_c, slots], -1)
-        best_payload_slot = payload_of(best_c, slots)              # (R, D)
-
-        # LRU refresh in ONE scatter: the reader's local hit plus every
-        # responder that served a query.  The scatter-max runs along the
-        # SHARED query set-index vector (R slice-updates, each vectorized
-        # over all C caches) with the per-cache way variability moved into
-        # the VALUES — XLA serializes per-element (C, R)-indexed scatters
-        # on CPU.
-        touch_cq = hit_fog_cq.at[r_gidx, slots].max(hit_local_slot)
-        touch_w = touch_cq[:, :, None] & (
-            jax.lax.iota(jnp.int32, cfg.cache_ways)[None, None, :]
-            == way_cq[:, :, None]
+    with _stage("probe"):
+        # 4d. fill the reader's local cache from fog/queue/store responses.
+        # Payload lanes are derived only for the R reader slots (non-slot lanes
+        # are valid=False in fill_lines, so their data is never read).
+        fill_ok_slot = fog_hit_slot | queue_hit_slot | found_slot
+        if spec.mutable:
+            # Queue/store fills carry the VERSION actually served; payloads are
+            # re-derived from (key, version) — identical to what the origin wrote.
+            slot_payload = jnp.where(
+                fog_hit_slot[:, None], best_payload_slot,
+                wl.versioned_payload(keys_q, served_ts_slot, cfg.payload_dim),
+            )
+            fill_ts_slot = jnp.where(fog_hit_slot, best_ts_slot, served_ts_slot)
+            fill_ts = jnp.full((n,), -1, jnp.int32).at[r_ids].set(
+                fill_ts_slot, mode="drop"
+            )
+            fill_origin = jnp.full((n,), -1, jnp.int32)
+        else:
+            slot_payload = jnp.where(
+                fog_hit_slot[:, None], best_payload_slot,
+                _payload_for(keys_q, cfg.payload_dim),                 # (R, D)
+            )
+            fill_ts = plan.r_fill_ts.at[r_ids].set(
+                jnp.where(fog_hit_slot, best_ts_slot, plan.r_fill_ts[r_gidx]),
+                mode="drop",
+            )
+            fill_origin = plan.r_src
+        fill_data = jnp.zeros((n, cfg.payload_dim), jnp.float32).at[r_ids].set(
+            slot_payload, mode="drop"
         )
-        caches = dataclasses.replace(
-            caches,
-            last_use=caches.last_use.at[:, sidx_q].max(jnp.where(touch_w, t, -1)),
+        fill_valid = jnp.zeros((n,), bool).at[r_ids].set(fill_ok_slot, mode="drop")
+        fill_lines = CacheLine(
+            key=r_keys,
+            data_ts=fill_ts,
+            origin=fill_origin,
+            data=fill_data,
+            valid=fill_valid,
+            dirty=jnp.zeros((n,), bool),
         )
+    with _stage("upsert"):
+        caches, _ev = insert_rows(caches, fill_lines, t, backend=cfg.probe_backend)
 
-        n_responses = jnp.sum(hit_fog_cq.astype(jnp.int32))
-    else:
-        # 4a+4b fused (fanout): the reader probes ONLY itself plus its K
-        # ring neighbors — (R, K+1) lanes, lane 0 local — so the probe,
-        # response loss, winner election, payload gather and LRU touch are
-        # all O(R·K), never O(N²).  Ties break by lane (nearest ring
-        # offset) instead of lowest node id: unobservable, because
-        # same-(key, ts) payloads are value-identical by construction.
-        cols = jnp.concatenate([r_gidx[:, None], nbr[r_gidx]], axis=1)
-        tags_l = caches.tags[cols, sidx_q[:, None]]                # (R, K+1, W)
-        valid_l = caches.valid[cols, sidx_q[:, None]]
-        match_l = valid_l & (tags_l == keys_q[:, None, None])
-        hit_l = jnp.any(match_l, axis=-1)                          # (R, K+1)
-        way_l = jnp.argmax(match_l, axis=-1).astype(jnp.int32)     # first-way wins
-        ts_raw_l = jnp.take_along_axis(
-            caches.data_ts[cols, sidx_q[:, None]], way_l[..., None], axis=-1
-        )[..., 0]
+    with _stage("probe"):
+        # 4e. staleness: served reads whose version is older than the newest
+        # write of that key (the soft-coherence lag the paper accepts, §I.A.a).
+        if spec.mutable:
+            served_slot = hit_local_slot | fog_hit_slot | queue_hit_slot | found_slot
+            got_ts_slot = jnp.where(
+                hit_local_slot, ts_local_slot,
+                jnp.where(fog_hit_slot, best_ts_slot, served_ts_slot),
+            )
+            truth_slot = latest_ts[jnp.clip(kids_q, 0, spec.key_universe - 1)]
+            n_stale = jnp.sum((served_slot & (got_ts_slot < truth_slot)).astype(jnp.int32))
+        else:
+            n_stale = jnp.int32(0)
 
-        hit_local_slot = hit_l[:, 0] & slot_ok                     # (R,)
-        need_fog_slot = slot_ok & ~hit_local_slot
-        ts_local_slot = jnp.where(hit_l[:, 0], ts_raw_l[:, 0], -1)
-
-        hit_fog_l = hit_l[:, 1:]                                   # (R, K)
-        resp_l = _response_mask_compact(cfg, channel, plan.k_resp, r_gidx, nbr)
-        if resp_l is not None:
-            hit_fog_l = hit_fog_l & resp_l
-        if spec.has_churn:
-            hit_fog_l = hit_fog_l & online[cols[:, 1:]]            # silent offline
-        hit_fog_l = hit_fog_l & need_fog_slot[:, None]
-        ts_fog_l = jnp.where(hit_fog_l, ts_raw_l[:, 1:], -1)
-
-        best_lane = jnp.argmax(ts_fog_l, axis=1)                   # (R,)
-        fog_hit_slot = jnp.any(hit_fog_l, axis=1)
-        best_ts_slot = jnp.where(fog_hit_slot, ts_fog_l[slots, best_lane], -1)
-        best_payload_slot = caches.data[
-            cols[slots, 1 + best_lane], sidx_q, way_l[slots, 1 + best_lane]
-        ]                                                          # (R, D)
-
-        # LRU refresh: flat scatter-max over the touched (cache, set, way)
-        # cells — O(R·K) updates, duplicates merge under max.
-        touch_l = jnp.concatenate([hit_local_slot[:, None], hit_fog_l], axis=1)
-        flat = (cols * cfg.cache_sets + sidx_q[:, None]) * cfg.cache_ways + way_l
-        oob = n * cfg.cache_sets * cfg.cache_ways
-        flat = jnp.where(touch_l, flat, oob)
-        caches = dataclasses.replace(
-            caches,
-            last_use=caches.last_use.reshape(-1)
-            .at[flat.reshape(-1)].max(t, mode="drop")
-            .reshape(caches.last_use.shape),
+    with _stage("writer"):
+        # ---- 5. writer drain + store commit --------------------------------
+        queue, n_drained, n_calls = wb.drain(
+            queue, t, healthy,
+            rate_per_tick=cfg.store.api_rate_per_tick,
+            burst=cfg.store.api_burst,
+            max_per_tick=cfg.writer_max_per_tick,
         )
-
-        n_responses = jnp.sum(hit_fog_l.astype(jnp.int32))
-
-    n_fog_queries = jnp.sum(need_fog_slot.astype(jnp.int32))
-
-    # 4c. writer-buffer forwarding, then the backing store (§VI).
-    healthy = bs.store_healthy(store_in, t)
-    need_store_slot = need_fog_slot & ~fog_hit_slot
-    if spec.mutable:
-        kids_q = plan.r_kids[r_gidx]
-        (queue_hit_slot, store_read_slot, failed_slot, found_slot,
-         served_ts_slot) = _resolve_backstop_keyed(
-            queue, store_in, healthy, need_store_slot, kids_q
-        )
-    else:
-        enq_idx_slot = plan.r_enq_idx[r_gidx]
-        queue_hit_slot, store_read_slot, failed_slot, found_slot, _ = _resolve_backstop(
-            queue, store_in, healthy, need_store_slot, enq_idx_slot
-        )
-    n_store_reads = jnp.sum(store_read_slot.astype(jnp.int32))
-    n_queue_hits = jnp.sum(queue_hit_slot.astype(jnp.int32))
-    n_failed = jnp.sum(failed_slot.astype(jnp.int32))
-    lan = (
-        lan + n_fog_queries * cfg.query_bytes
-        + (n_responses + n_queue_hits) * cfg.row_bytes
-    )
-    txn = cfg.store.read_txn_bytes(store_in.drained_total)
-    wan_rx = n_store_reads.astype(jnp.float32) * txn
-    store = dataclasses.replace(
-        store_in, api_calls=store_in.api_calls + n_store_reads
-    )
-
-    # 4d. fill the reader's local cache from fog/queue/store responses.
-    # Payload lanes are derived only for the R reader slots (non-slot lanes
-    # are valid=False in fill_lines, so their data is never read).
-    fill_ok_slot = fog_hit_slot | queue_hit_slot | found_slot
-    if spec.mutable:
-        # Queue/store fills carry the VERSION actually served; payloads are
-        # re-derived from (key, version) — identical to what the origin wrote.
-        slot_payload = jnp.where(
-            fog_hit_slot[:, None], best_payload_slot,
-            wl.versioned_payload(keys_q, served_ts_slot, cfg.payload_dim),
-        )
-        fill_ts_slot = jnp.where(fog_hit_slot, best_ts_slot, served_ts_slot)
-        fill_ts = jnp.full((n,), -1, jnp.int32).at[r_ids].set(
-            fill_ts_slot, mode="drop"
-        )
-        fill_origin = jnp.full((n,), -1, jnp.int32)
-    else:
-        slot_payload = jnp.where(
-            fog_hit_slot[:, None], best_payload_slot,
-            _payload_for(keys_q, cfg.payload_dim),                 # (R, D)
-        )
-        fill_ts = plan.r_fill_ts.at[r_ids].set(
-            jnp.where(fog_hit_slot, best_ts_slot, plan.r_fill_ts[r_gidx]),
-            mode="drop",
-        )
-        fill_origin = plan.r_src
-    fill_data = jnp.zeros((n, cfg.payload_dim), jnp.float32).at[r_ids].set(
-        slot_payload, mode="drop"
-    )
-    fill_valid = jnp.zeros((n,), bool).at[r_ids].set(fill_ok_slot, mode="drop")
-    fill_lines = CacheLine(
-        key=r_keys,
-        data_ts=fill_ts,
-        origin=fill_origin,
-        data=fill_data,
-        valid=fill_valid,
-        dirty=jnp.zeros((n,), bool),
-    )
-    caches, _ev = insert_rows(caches, fill_lines, t, backend=cfg.probe_backend)
-
-    # 4e. staleness: served reads whose version is older than the newest
-    # write of that key (the soft-coherence lag the paper accepts, §I.A.a).
-    if spec.mutable:
-        served_slot = hit_local_slot | fog_hit_slot | queue_hit_slot | found_slot
-        got_ts_slot = jnp.where(
-            hit_local_slot, ts_local_slot,
-            jnp.where(fog_hit_slot, best_ts_slot, served_ts_slot),
-        )
-        truth_slot = latest_ts[jnp.clip(kids_q, 0, spec.key_universe - 1)]
-        n_stale = jnp.sum((served_slot & (got_ts_slot < truth_slot)).astype(jnp.int32))
-    else:
-        n_stale = jnp.int32(0)
-
-    # ---- 5. writer drain + store commit ------------------------------------
-    queue, n_drained, n_calls = wb.drain(
-        queue, t, healthy,
-        rate_per_tick=cfg.store.api_rate_per_tick,
-        burst=cfg.store.api_burst,
-        max_per_tick=cfg.writer_max_per_tick,
-    )
-    store = bs.commit_writes(store, n_drained, n_calls, plan.k_coll, cfg.store)
-    if spec.mutable:
-        d_kids, d_ts, d_live = wb.drained_entries(
-            queue, n_drained, cfg.writer_max_per_tick
-        )
-        store = bs.commit_keyed_rows(store, d_kids, d_ts, d_live)
-    wan_tx = cfg.store.write_txn_bytes(n_drained)
+        store = bs.commit_writes(store, n_drained, n_calls, plan.k_coll, cfg.store)
+        if spec.mutable:
+            d_kids, d_ts, d_live = wb.drained_entries(
+                queue, n_drained, cfg.writer_max_per_tick
+            )
+            store = bs.commit_keyed_rows(store, d_kids, d_ts, d_live)
+        wan_tx = cfg.store.write_txn_bytes(n_drained)
 
     # ---- 6. latency model + baseline accounting ----------------------------
     n_reads = jnp.sum(reading.astype(jnp.int32))

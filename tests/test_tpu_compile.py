@@ -9,6 +9,7 @@ skips where the TPU compiler cannot be loaded.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,3 +119,27 @@ def test_fused_scan_with_pallas_kernels_compiles(one_chip, scenario):
     )
     hlo = _run_scan.lower(cfg, 30, state, 1, "fused").compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+def test_fused_scan_keeps_every_stage_in_its_loop_body(one_chip):
+    """The tick's named stages survive the chip's compiler: each of the five
+    owns an instruction of the compiled loop body (by the innermost
+    ``stage.<x>`` of its ``op_name``), on a mutable Zipf config whose
+    coherence sweep runs."""
+    cfg = SimConfig(n_nodes=32, cache_lines=200, payload_dim=8,
+                    workload=dataclasses.replace(SCENARIOS["zipf_hot"], fanout=8))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_sim(cfg)),
+    )
+    hlo = _run_scan.lower(cfg, 4, state, 1, "fused").compile().as_text()
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", hlo))
+    found, comp = set(), None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([^\s(]+)\s.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+        elif comp in bodies:
+            found.update(re.findall(r'op_name="[^"]*stage\.(\w+)', line))
+    assert bodies
+    assert {"plan", "probe", "sweep", "upsert", "writer"} <= found
