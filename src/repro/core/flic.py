@@ -159,14 +159,13 @@ def insert_batch(
 #
 # These are the hot-path versions of ``insert`` / ``local_lookup`` for a
 # *batched* ``CacheState`` with leading node axis N: each node i upserts or
-# probes its own lane i.  Per field this lowers to ONE gather of the probed
-# set row and ONE scatter-lean write — no vmap-of-scalar chains and no
-# (N, S, W)-materializing one-hot selects (DESIGN.md §3).  The scatter form
-# matters on CPU/TPU alike: each lane writes exactly ONE flat line index
-# ``(node*S + set)*W + way`` — unique per lane, so the scatter carries the
-# uniqueness hint and skips XLA's conflict-safe serialization; no-op lanes
-# keep the masked out-of-bounds-drop trick (all routed to the single OOB
-# slot, never applied).
+# probes its own lane i, with no vmap-of-scalar chains (DESIGN.md §3).
+# ``insert_rows`` reads each probed set row by a masked reduce and writes
+# every table by a masked select, both in the table's own (N, S, W[, D])
+# shape, so on the TPU no table is relaid out (DESIGN §3).  ``lookup_rows``'s
+# LRU touch writes one flat line index ``(node*S + set)*W + way`` per lane:
+# unique per lane, so the scatter carries the uniqueness hint, and no-op
+# lanes go to the one out-of-bounds slot, which is dropped.
 # Semantics match ``insert``/``local_lookup`` exactly: first-matching-way on
 # hit, first-invalid-else-LRU victim, strictly-newer timestamp overwrites.
 # --------------------------------------------------------------------------
@@ -175,6 +174,17 @@ def _gather_rows(field: jax.Array, sidx: jax.Array) -> jax.Array:
     """field (N, S, W[, D]), sidx (N,) -> the probed set row (N, W[, D])."""
     idx = sidx.reshape(sidx.shape + (1,) * (field.ndim - 1))
     return jnp.take_along_axis(field, idx, axis=1)[:, 0]
+
+
+def pick_rows(field: jax.Array, hot: jax.Array) -> jax.Array:
+    """Reduce away axis 1 of ``field`` where ``hot`` (broadcast against it)
+    holds exactly one True per row: the one picked element, exactly, as a
+    masked reduce in the table's own shape.  On the TPU a gather of W-wide
+    set rows would hold every table W-minor, its 4 ways padded to 128
+    lanes."""
+    if field.dtype == jnp.bool_:
+        return jnp.any(hot & field, axis=1)
+    return jnp.sum(jnp.where(hot, field, 0), axis=1, dtype=field.dtype)
 
 
 def _select_way_rows(tags_r, valid_r, use_r, keys):
@@ -200,10 +210,10 @@ def insert_rows(
 ) -> tuple[CacheState, CacheLine | None]:
     """Upsert one line per node across a batched cache (leading axis N).
 
-    Equivalent to ``jax.vmap(insert)(caches, lines)`` but built from one
-    gather + one one-hot scatter per field.  Returns (caches, evictions)
-    with evictions batched over N; masked lanes (``lines.valid`` False) are
-    no-ops, exactly like the scalar path.
+    Equivalent to ``jax.vmap(insert)(caches, lines)`` but built from masked
+    reduces that read the probed set row and one masked select per table.
+    Returns (caches, evictions) with evictions batched over N; masked lanes
+    (``lines.valid`` False) are no-ops, exactly like the scalar path.
 
     ``backend`` "xla" | "interpret" | "pallas" dispatches the upsert through
     ``repro.kernels.ops.flic_insert`` (the ``kernels/flic_insert.py`` Pallas
@@ -222,55 +232,56 @@ def insert_rows(
     now = jnp.asarray(now, jnp.int32)
     sidx = (keys % jnp.uint32(s_sets)).astype(jnp.int32)            # (N,)
 
-    tags_r = _gather_rows(caches.tags, sidx)          # (N, W)
-    valid_r = _gather_rows(caches.valid, sidx)
-    use_r = _gather_rows(caches.last_use, sidx)
+    s_hot = jnp.arange(s_sets)[None, :, None] == sidx[:, None, None]  # (N, S, 1)
+    tags_r = pick_rows(caches.tags, s_hot)           # (N, W)
+    valid_r = pick_rows(caches.valid, s_hot)
+    use_r = pick_rows(caches.last_use, s_hot)
     way, present = _select_way_rows(tags_r, valid_r, use_r, keys)
 
-    rows = jnp.arange(n)
-    old_ts = caches.data_ts[rows, sidx, way]
-    old_valid = valid_r[rows, way]
+    w_hot = jnp.arange(w_ways)[None, :] == way[:, None]               # (N, W)
+
+    def pick_line(field):                             # the elected line (N,)
+        return pick_rows(pick_rows(field, s_hot), w_hot)
+
+    old_ts = pick_line(caches.data_ts)
+    old_valid = pick_rows(valid_r, w_hot)
     line_ts = jnp.asarray(lines.data_ts, jnp.int32)
     stale_incoming = present & (line_ts <= old_ts)
     do_write = jnp.asarray(lines.valid) & ~stale_incoming
 
     displaced = do_write & ~present & old_valid
     evicted = CacheLine(
-        key=jnp.where(displaced, tags_r[rows, way], NULL_TAG),
+        key=jnp.where(displaced, pick_rows(tags_r, w_hot), NULL_TAG),
         data_ts=jnp.where(displaced, old_ts, -1),
-        origin=jnp.where(displaced, caches.origin[rows, sidx, way], -1),
+        origin=jnp.where(displaced, pick_line(caches.origin), -1),
         data=jnp.where(
-            displaced[:, None], caches.data[rows, sidx, way],
+            displaced[:, None], caches.data[jnp.arange(n), sidx, way],
             jnp.zeros_like(lines.data),
         ),
         valid=displaced,
-        dirty=displaced & caches.dirty[rows, sidx, way],
+        dirty=displaced & pick_line(caches.dirty),
     )
 
-    # Scatter-lean write: each lane targets its own FLAT line index (no-op
-    # lanes route to the shared out-of-bounds slot and are dropped).  Live
-    # indices are unique by construction — one slot per lane — and the
-    # dropped ones are never applied, so the uniqueness hint is sound; it
-    # lets XLA skip the conflict-safe serialization of the general scatter.
-    flat = jnp.where(do_write, (rows * s_sets + sidx) * w_ways + way,
-                     n * s_sets * w_ways)
+    # Dense masked write in the tables' own (N, S, W[, D]) shape: the one
+    # line each writing lane elects is the only True of ``hit``.  A flat
+    # line-index scatter would view every table as (N*S*W[, D]); on the TPU
+    # that view costs a relayout of the whole table into 128-lane tiles and
+    # back (DESIGN §3).
+    hit = do_write[:, None, None] & s_hot & w_hot[:, None, :]
 
     def wr(field, value):
-        return field.reshape(-1).at[flat].set(
-            value.astype(field.dtype), mode="drop", unique_indices=True
-        ).reshape(field.shape)
+        return jnp.where(hit, jnp.asarray(value, field.dtype)[..., None, None],
+                         field)
 
     caches = CacheState(
         tags=wr(caches.tags, keys),
         data_ts=wr(caches.data_ts, line_ts),
-        ins_ts=wr(caches.ins_ts, jnp.full((n,), now)),
-        origin=wr(caches.origin, jnp.asarray(lines.origin, jnp.int32)),
-        valid=wr(caches.valid, jnp.ones((n,), bool)),
-        dirty=wr(caches.dirty, jnp.asarray(lines.dirty)),
-        last_use=wr(caches.last_use, jnp.full((n,), now)),
-        data=caches.data.reshape(n * s_sets * w_ways, -1).at[flat].set(
-            lines.data, mode="drop", unique_indices=True
-        ).reshape(caches.data.shape),
+        ins_ts=wr(caches.ins_ts, now),
+        origin=wr(caches.origin, lines.origin),
+        valid=wr(caches.valid, True),
+        dirty=wr(caches.dirty, lines.dirty),
+        last_use=wr(caches.last_use, now),
+        data=jnp.where(hit[..., None], lines.data[:, None, None, :], caches.data),
     )
     return caches, evicted
 

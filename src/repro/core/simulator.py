@@ -63,6 +63,7 @@ from typing import Literal, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core import backing_store as bs
 from repro.core import workload as wl
@@ -74,7 +75,7 @@ from repro.core.coherence import (
     gilbert_elliott_advance,
     gilbert_elliott_mask,
 )
-from repro.core.flic import insert_rows, invalidate_nodes, update_rows
+from repro.core.flic import insert_rows, invalidate_nodes, pick_rows, update_rows
 from repro.core.metrics import TickMetrics, windowed_scan
 
 # Payload derivation lives in the workload layer now; keep the old name —
@@ -226,6 +227,44 @@ def _neighbor_index(cfg: SimConfig):
     if cfg.workload.fanout is None:
         return None
     return jnp.asarray(wl.neighbor_table(cfg.n_nodes, cfg.workload.fanout))
+
+
+def _node_minor(caches):
+    """Pin the (N, S, W) tables to a node-minor layout (the node axis in
+    the TPU's 128 lanes, the 4 ways in sublanes).  Left to itself the
+    compiler may hold them W-minor, padding 4 ways to 128 lanes: 32 times
+    the bytes in every pass over a table (PERF.md §6)."""
+    node_minor = Layout((1, 2, 0))
+    return dataclasses.replace(caches, **{
+        f: with_layout_constraint(getattr(caches, f), node_minor)
+        for f in ("tags", "data_ts", "ins_ts", "origin", "valid", "dirty", "last_use")
+    })
+
+
+def _ring_lanes(cfg: SimConfig, sidx):
+    """The fan-out probe's reads of the cache tables, as whole-table passes.
+
+    Returns ``lanes(table) -> (N, K+1, W)`` with ``lanes(table)[i, j] =
+    table[(i + off_j) mod N, sidx[i]]``: lane 0 is node i itself, lane j its
+    ring neighbour ``nbr[i, j-1]``.  Each lane is a static roll along the
+    node axis and a masked reduce over the sets, exact for every dtype.  A
+    gather of the lanes' set rows would make the compiler hold every
+    ``(N, S, W)`` table W-minor on the TPU (4 ways padded to 128 lanes),
+    and element gathers cost ~12 ns each there (PERF.md §6).
+    """
+    nbr0 = wl.neighbor_table(cfg.n_nodes, cfg.workload.fanout)[0]
+    offs = [0] + [int(c) for c in nbr0]                   # off_j mod N
+    sets = jax.lax.iota(jnp.int32, cfg.cache_sets)
+
+    def lanes(table):
+        # q[j] = table[j, sidx[j - off]], so q[i + off] = table[i + off, sidx[i]].
+        return jnp.stack([
+            jnp.roll(pick_rows(table, sets[None, :, None]
+                               == jnp.roll(sidx, off)[:, None, None]), -off, axis=0)
+            for off in offs
+        ], axis=1)
+
+    return lanes
 
 
 def _expand_lanes_dense(lanes, nbr, n: int):
@@ -458,7 +497,7 @@ def sim_tick(cfg: SimConfig, state: SimState, _=None) -> tuple[SimState, TickMet
         # engine only executes the returned tensors.
         plan = wl.plan_tick(cfg, state.plan, t, state.rng)
         m = TickMetrics.zeros()
-        caches = state.caches
+        caches = _node_minor(state.caches)
         latest_ts = state.latest_ts
         store_in = state.store
         if cfg.outage_schedule:
@@ -605,13 +644,15 @@ def sim_tick(cfg: SimConfig, state: SimState, _=None) -> tuple[SimState, TickMet
             # offset) instead of lowest node id: unobservable, because
             # same-(key, ts) payloads are value-identical by construction.
             cols = jnp.concatenate([r_gidx[:, None], nbr[r_gidx]], axis=1)
-            tags_l = caches.tags[cols, sidx_q[:, None]]                # (R, K+1, W)
-            valid_l = caches.valid[cols, sidx_q[:, None]]
+            sidx_n = (r_keys % jnp.uint32(cfg.cache_sets)).astype(jnp.int32)
+            lanes = _ring_lanes(cfg, sidx_n)
+            tags_l = lanes(caches.tags)[r_gidx]                        # (R, K+1, W)
+            valid_l = lanes(caches.valid)[r_gidx]
             match_l = valid_l & (tags_l == keys_q[:, None, None])
             hit_l = jnp.any(match_l, axis=-1)                          # (R, K+1)
             way_l = jnp.argmax(match_l, axis=-1).astype(jnp.int32)     # first-way wins
             ts_raw_l = jnp.take_along_axis(
-                caches.data_ts[cols, sidx_q[:, None]], way_l[..., None], axis=-1
+                lanes(caches.data_ts)[r_gidx], way_l[..., None], axis=-1
             )[..., 0]
 
             hit_local_slot = hit_l[:, 0] & slot_ok                     # (R,)
@@ -796,7 +837,7 @@ def sim_tick(cfg: SimConfig, state: SimState, _=None) -> tuple[SimState, TickMet
         churn_rejoins=n_rejoin,
     )
     new_state = SimState(
-        caches=caches, queue=queue, store=store, channel=channel,
+        caches=_node_minor(caches), queue=queue, store=store, channel=channel,
         tick=t + 1, rng=plan.rng_next, latest_ts=latest_ts,
         plan=plan.state_next,
     )

@@ -114,14 +114,56 @@ def test_insert_rows_kernel_matches_inline(seed, n, s, w, pool):
     _assert_same_upsert(caches, lines, jnp.int32(99))
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_insert_rows_inline_matches_scalar_vmap(seed):
-    """The inline flat-scatter path is itself pinned to the scalar
-    ``insert`` semantics (vmap over nodes) — the kernels' source of truth
-    is therefore the paper's single-node upsert, transitively."""
+# (seed, N, S, W, D, fill): the first three are the original small cases;
+# the rest run the cells' payload widths (37 lanes = the paper's 148-B row,
+# 250 = YCSB's 1000-B record) at node counts that divide the kernel's
+# 8-node block and that do not.
+INLINE_CASES = [
+    (0, 6, 4, 2, 4, 0.6),
+    (1, 6, 4, 2, 4, 0.6),
+    (2, 6, 4, 2, 4, 0.6),
+    (3, 8, 4, 4, 37, 0.9),
+    (4, 13, 4, 4, 37, 0.9),
+    (5, 16, 2, 4, 250, 0.9),
+    (6, 5, 4, 2, 250, 0.8),
+    (7, 24, 8, 4, 37, 0.95),
+    (8, 11, 2, 2, 4, 0.7),
+]
+
+
+def _plant_branches(caches, lines, s):
+    """Lanes 0-3 take each branch of the upsert on top of a random state:
+    a present key overwritten by a strictly newer row, a present key met by
+    a row of equal timestamp (stale), a masked lane, and a new key into a
+    full set (an LRU victim).  Returns the planted (caches, lines, sets)."""
+    c = {f: np.array(getattr(caches, f)) for f in FIELDS}
+    ln = {f: np.array(getattr(lines, f))
+          for f in ("key", "data_ts", "origin", "data", "valid", "dirty")}
+    sets = ln["key"][:4] % np.uint32(s)
+    for node, ts in ((0, 10), (1, 30)):        # present: newer, then stale
+        c["tags"][node, sets[node], 0] = ln["key"][node]
+        c["valid"][node, sets[node], 0] = True
+        c["data_ts"][node, sets[node], 0] = ts
+    ln["data_ts"][:2] = 20, 30
+    ln["valid"][[0, 1, 3]] = True
+    ln["valid"][2] = False                     # masked
+    w = c["tags"].shape[2]                     # full set, key absent
+    c["tags"][3, sets[3]] = ln["key"][3] ^ np.arange(1, w + 1, dtype=np.uint32)
+    c["valid"][3, sets[3]] = True
+    return (dataclasses.replace(caches, **{f: jnp.asarray(v) for f, v in c.items()}),
+            CacheLine(**{f: jnp.asarray(v) for f, v in ln.items()}), sets)
+
+
+@pytest.mark.parametrize("seed,n,s,w,d,fill", INLINE_CASES)
+def test_insert_rows_inline_matches_scalar_vmap(seed, n, s, w, d, fill):
+    """The inline upsert is pinned to the scalar ``insert`` semantics (vmap
+    over nodes) — the kernels' source of truth is therefore the paper's
+    single-node upsert, transitively.  Every case holds each branch of the
+    upsert (``_plant_branches``) among random lanes."""
     rng = np.random.default_rng(seed)
     key_pool = rng.integers(0, 2**32, 8, dtype=np.uint32)
-    caches, lines = _random_state(rng, 6, 4, 2, 4, key_pool)
+    caches, lines = _random_state(rng, n, s, w, d, key_pool, fill=fill)
+    caches, lines, sets = _plant_branches(caches, lines, s)
     rows_c, rows_ev = insert_rows(caches, lines, jnp.int32(99))
     vmap_c, vmap_ev = jax.vmap(insert, in_axes=(0, 0, None))(
         caches, lines, jnp.int32(99)
@@ -136,6 +178,12 @@ def test_insert_rows_inline_matches_scalar_vmap(seed):
             np.asarray(getattr(rows_ev, f)), np.asarray(getattr(vmap_ev, f)),
             err_msg=f"evicted.{f}",
         )
+    assert int(rows_c.data_ts[0, sets[0], 0]) == 20           # newer wins
+    assert int(rows_c.data_ts[1, sets[1], 0]) == 30           # stale kept
+    for f in FIELDS:                                          # masked lane
+        np.testing.assert_array_equal(np.asarray(getattr(rows_c, f))[2],
+                                      np.asarray(getattr(caches, f))[2])
+    assert bool(rows_ev.valid[3])                              # LRU victim
 
 
 @pytest.mark.parametrize("seed", range(4))

@@ -121,25 +121,68 @@ def test_fused_scan_with_pallas_kernels_compiles(one_chip, scenario):
     assert "tpu_custom_call" in hlo
 
 
+def _fused_scan_text(one_chip, payload_dim, scenario="zipf_hot"):
+    cfg = SimConfig(n_nodes=32, cache_lines=200, payload_dim=payload_dim,
+                    workload=dataclasses.replace(SCENARIOS[scenario], fanout=8))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_sim(cfg)),
+    )
+    return cfg, _run_scan.lower(cfg, 4, state, 1, "fused").compile().as_text()
+
+
+def _loop_body_ops(hlo):
+    """(innermost stage or None, result shape) of every instruction in the
+    compiled loop bodies; a tuple-shaped result gives the empty shape."""
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", hlo))
+    assert bodies
+    comp = None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([^\s(]+)\s.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        if comp not in bodies:
+            continue
+        op = re.match(r"\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(?:\w+\[([\d,]*)\])?", line)
+        if op is None:
+            continue
+        scope = re.search(r'op_name="([^"]*)"', line)
+        stages = re.findall(r"stage\.(\w+)", scope.group(1)) if scope else []
+        dims = op.group(1)
+        shape = tuple(int(d) for d in dims.split(",") if d) if dims else ()
+        yield (stages[-1] if stages else None), shape
+
+
 def test_fused_scan_keeps_every_stage_in_its_loop_body(one_chip):
     """The tick's named stages survive the chip's compiler: each of the five
     owns an instruction of the compiled loop body (by the innermost
     ``stage.<x>`` of its ``op_name``), on a mutable Zipf config whose
     coherence sweep runs."""
-    cfg = SimConfig(n_nodes=32, cache_lines=200, payload_dim=8,
-                    workload=dataclasses.replace(SCENARIOS["zipf_hot"], fanout=8))
-    state = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
-        jax.eval_shape(lambda: init_sim(cfg)),
-    )
-    hlo = _run_scan.lower(cfg, 4, state, 1, "fused").compile().as_text()
-    bodies = set(re.findall(r"body=%?([\w.\-]+)", hlo))
-    found, comp = set(), None
-    for line in hlo.splitlines():
-        head = re.match(r"^(?:ENTRY\s+)?%?([^\s(]+)\s.*\{\s*$", line)
-        if head:
-            comp = head.group(1)
-        elif comp in bodies:
-            found.update(re.findall(r'op_name="[^"]*stage\.(\w+)', line))
-    assert bodies
+    _, hlo = _fused_scan_text(one_chip, 8)
+    found = {stage for stage, _ in _loop_body_ops(hlo)}
     assert {"plan", "probe", "sweep", "upsert", "writer"} <= found
+
+
+@pytest.mark.parametrize("scenario", ["paper", "zipf_hot"])
+def test_fused_upsert_writes_tables_in_their_own_shape(one_chip, scenario):
+    """At the paper's 37 payload lanes, no instruction of the upsert views a
+    cache table as flat lines, ``[N*S*W]`` or ``[N*S*W, D]``: on the chip
+    such a view relays the whole table out and back every call.  The scan
+    carries the seven ``[N, S, W]`` tables node-minor, so their 4 ways are
+    not padded to 128 lanes (left to itself, the compiler holds the write-once
+    stream's tables W-minor), and every stage the scenario runs still owns
+    instructions of the loop body."""
+    cfg, hlo = _fused_scan_text(one_chip, 37, scenario)
+    lines = cfg.n_nodes * cfg.cache_lines
+    ops = list(_loop_body_ops(hlo))
+    flat = [shape for stage, shape in ops
+            if stage == "upsert" and shape in ((lines,), (lines, cfg.payload_dim))]
+    assert not flat
+    table = rf"\w+\[{cfg.n_nodes},{cfg.cache_sets},{cfg.cache_ways}\]\{{([\d,]+)"
+    carried = [re.findall(table, carry)
+               for carry in re.findall(r"= \((.*?)\) while\(", hlo)]
+    assert [layouts for layouts in carried if layouts] == [["0,2,1"] * 7]
+    stages = {"plan", "probe", "upsert", "writer"} | (
+        {"sweep"} if cfg.workload.mutable else set())
+    assert stages <= {s for s, _ in ops}
