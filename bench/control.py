@@ -56,7 +56,8 @@ def main(argv=None) -> int:
     cfg = cells.sim_config(cell)
     seeds = [int(s) for s in args.seeds.split(",")]
     lower, upper, passed = {}, {}, 0
-    fault = faults.planted(args.fault) if args.fault else contextlib.nullcontext()
+    fault = (faults.planted(args.fault, cell.engine) if args.fault
+             else contextlib.nullcontext())
     with fault:
         for seed in seeds:
             prog, snap, info = control.program_numbers(
@@ -66,7 +67,7 @@ def main(argv=None) -> int:
                     "program": prog, "correct": check.correct(prog)}
             if not args.fault:
                 line["control"] = control.control_numbers(
-                    cell.spec, seed, snap, cell.chunk_ticks)
+                    cell, cell.spec, seed, snap)
                 for k, v in line["control"].items():
                     upper[k] = min(upper.get(k, v), v)
             print(json.dumps(line), flush=True)

@@ -1,14 +1,19 @@
-"""Find a cell's files by the names in ``BENCHMARK.json`` and build its run.
+"""Find a cell's files, and its engine, by the names in ``BENCHMARK.json``.
 
 A cell names a configuration (``bench/configs/<config>.json``) and a traffic
 mix (``bench/traffic/<traffic>.json``); its run parameters (chunk length,
 warm-up, the replayed prefix) sit in ``bench/cells/<cell>.json``, and every
-metric has a reader ``bench/metrics/<metric>.py``.  Nothing here names a
-cell, so a new cell, configuration, mix or metric is new files and entries.
+metric has a reader ``bench/metrics/<metric>.py``.  The configuration's
+``engine`` key names the module ``bench/harness/engines/<engine>.py`` that
+builds, runs and reads the program's state and holds its plain reference.
+Nothing here names a cell or an engine, so a new cell, configuration, mix,
+metric or engine is new files and entries.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib.util
 import json
 from pathlib import Path
 
@@ -25,6 +30,7 @@ class Cell:
     name: str
     config: str
     traffic: str
+    engine: object                # the module bench/harness/engines/<engine>.py
     chips: int
     chunk_ticks: int
     warm_ticks: int
@@ -64,8 +70,8 @@ def load_cell(name: str) -> Cell:
         raise ValueError(f"{name}: ref_chunks [{lo}, {hi}) must lie past the "
                          f"{warm // chunk} warm-up chunks")
     return Cell(name=name, config=w["config"], traffic=w["traffic"],
-                chips=int(w["chips"]), chunk_ticks=chunk, warm_ticks=warm,
-                ref_chunks=(lo, hi), spec=spec,
+                engine=engine_module(conf["engine"]), chips=int(w["chips"]),
+                chunk_ticks=chunk, warm_ticks=warm, ref_chunks=(lo, hi), spec=spec,
                 end_to_end=tuple(bench["end_to_end"]),
                 per_layer=tuple(bench["per_layer"]))
 
@@ -95,14 +101,30 @@ def sim_config(cell: Cell, **sim_overrides):
     )
 
 
+def _load(prefix: str, path: Path):
+    spec = importlib.util.spec_from_file_location(f"{prefix}_{path.stem}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def metric_reader(name: str):
     """The ``read(run) -> float | None`` of ``bench/metrics/<name>.py``."""
-    import importlib.util
-
     path = BENCH / "metrics" / f"{name}.py"
     if not path.is_file():
         raise FileNotFoundError(f"metric {name!r} has no reader at {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _load("bench_metric", path).read
+
+
+def engine_module(name: str):
+    """The module ``bench/harness/engines/<name>.py``, loaded once per path,
+    so every cell of an engine shares it (and a fault planted in it)."""
+    path = BENCH / "harness" / "engines" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"engine {name!r} has no module at {path}")
+    return _engine_at(path)
+
+
+@functools.cache
+def _engine_at(path: Path):
+    return _load("bench_engine", path)

@@ -12,9 +12,10 @@ modelled-latency row field, which the program sums in float32.
   PRNG key) that differ from the reference's;
 * ``invariant_violations``: over every chunk of the run, reads against the
   read schedule, writes against one per node per tick, the read partition
-  (local + fog + ring + store), drain batches within the writer's bound,
-  and at the end the conservation of writes (drained + pending + dropped +
-  coalesced) and the state's tick;
+  (local + fog + ring + store), drain batches within the bound of the
+  engine's writers, and at the end the conservation of writes (drained +
+  pending + dropped + coalesced, summed over the writer rings) and the
+  state's tick;
 * ``bad_lines``: valid lines of the final caches whose key lies in the wrong
   set, whose payload is not the one its key (and version) derive, whose
   version is from the future, or (Zipf keys) whose key is no key id's hash.
@@ -43,29 +44,6 @@ def rows_to_host(rows) -> list[dict]:
         out.append({f: np.asarray(getattr(row, f)).reshape(-1)[0]
                     for f in ref.INT_FIELDS + ref.FLOAT_FIELDS})
     return out
-
-
-def state_leaves(state) -> dict:
-    """The program's ``SimState`` under the reference's names, on the host."""
-    import jax
-
-    c, q, s = state.caches, state.queue, state.store
-    leaves = {
-        "caches.tags": c.tags, "caches.data_ts": c.data_ts,
-        "caches.ins_ts": c.ins_ts, "caches.origin": c.origin,
-        "caches.valid": c.valid, "caches.dirty": c.dirty,
-        "caches.last_use": c.last_use, "caches.data": c.data,
-        "queue.keys": q.keys, "queue.data_ts": q.data_ts,
-        "queue.origin": q.origin, "queue.head": q.head, "queue.tail": q.tail,
-        "queue.dropped": q.dropped, "queue.backoff": q.backoff,
-        "queue.next_retry": q.next_retry, "queue.tokens": q.tokens,
-        "queue.slot_of_key": q.slot_of_key, "queue.coalesced": q.coalesced,
-        "store.drained_total": s.drained_total, "store.api_calls": s.api_calls,
-        "store.outage_until": s.outage_until, "store.lost_writes": s.lost_writes,
-        "store.table_ts": s.table_ts, "tick": state.tick, "rng": state.rng,
-        "latest_ts": state.latest_ts, "plan.cum_writes": state.plan.cum_writes,
-    }
-    return {k: np.asarray(v) for k, v in jax.device_get(leaves).items()}
 
 
 def _gap(a, b) -> float:
@@ -116,12 +94,21 @@ def expected_reads(n: int, period: int, t0: int, t1: int) -> int:
     return int((((t + node) % period == 0) & (t > 0)).sum())
 
 
-def invariants(rows: list[dict], final: dict, spec: dict, chunk_ticks: int):
+def _total(final: dict, name: str) -> int:
+    """A leaf summed over its leading ring axis, where it has one."""
+    return int(np.sum(np.asarray(final[name], np.int64)))
+
+
+def invariants(rows: list[dict], final: dict, spec: dict, chunk_ticks: int,
+               rings: int):
     """(violations, chunks that violate).  ``final`` is the final state's
-    leaves; rows cover every chunk from tick 0."""
+    leaves; rows cover every chunk from tick 0.  ``rings`` writers drain
+    ``rings`` rings, each up to ``writer_max_per_tick`` rows a tick; the
+    ring and store leaves of ``final`` carry a leading axis of ``rings``
+    where that is more than one."""
     sim = spec["sim"]
     n, period = int(sim["n_nodes"]), int(sim["read_period"])
-    max_drain = int(sim["writer_max_per_tick"])
+    max_drain = rings * int(sim["writer_max_per_tick"])
     bad, violations = set(), 0
     for i, r in enumerate(rows):
         t0 = i * chunk_ticks
@@ -146,10 +133,11 @@ def invariants(rows: list[dict], final: dict, spec: dict, chunk_ticks: int):
         total["writes_gen"] == total["writes_drained"] + int(last["queue_depth"])
         + int(last["queue_dropped"]) + total["writes_coalesced"],
         int(final["tick"]) == len(rows) * chunk_ticks,
-        int(final["store.drained_total"]) + int(final["store.lost_writes"])
+        _total(final, "store.drained_total") + _total(final, "store.lost_writes")
         == total["writes_drained"],
-        int(final["queue.tail"]) - int(final["queue.head"]) == int(last["queue_depth"]),
-        int(final["queue.dropped"]) == int(last["queue_dropped"]),
+        _total(final, "queue.tail") - _total(final, "queue.head")
+        == int(last["queue_depth"]),
+        _total(final, "queue.dropped") == int(last["queue_dropped"]),
     ]
     if False in final_checks:
         violations += final_checks.count(False)
@@ -185,13 +173,14 @@ def bad_lines(final: dict, spec: dict) -> int:
     return int(wrong.sum())
 
 
-def replay(spec: dict, seed: int, chunks: int, chunk_ticks: int,
+def replay(reference, spec: dict, seed: int, chunks: int, chunk_ticks: int,
            payload_dtype=np.float32):
-    """The reference's rows for the first ``chunks`` chunks, its state, and
-    what the replay covered: ticks, LRU evictions (all of them, and in the
-    last quarter of the ticks, where the caches are full) and seconds."""
+    """The rows of the plain reference (an engine's ``Reference`` class) for
+    the first ``chunks`` chunks, its state, and what the replay covered:
+    ticks, LRU evictions (all of them, and in the last quarter of the ticks,
+    where the caches are full) and seconds."""
     t0 = time.perf_counter()
-    sim = ref.ReferenceSim(spec, seed, payload_dtype=payload_dtype)
+    sim = reference(spec, seed, payload_dtype=payload_dtype)
     rows = [sim.chunk(chunk_ticks) for _ in range(chunks - chunks // 4)]
     early = sim.evictions
     rows += [sim.chunk(chunk_ticks) for _ in range(chunks // 4)]
@@ -201,16 +190,19 @@ def replay(spec: dict, seed: int, chunks: int, chunk_ticks: int,
     return rows, sim.state(), info
 
 
-def verify(rows: list[dict], snapshot: dict, final: dict, snap_chunk: int,
-           spec: dict, seed: int, chunk_ticks: int):
+def verify(engine, rows: list[dict], snapshot: dict, final: dict,
+           snap_chunk: int, spec: dict, seed: int, chunk_ticks: int):
     """Returns (numbers compared, chunks that failed, what the replay
-    covered).  A chunk fails when its row does; the snapshot chunk when the
-    state differs; the last chunk when the final state breaks an invariant
-    or holds a bad line."""
-    ref_rows, ref_state, info = replay(spec, seed, snap_chunk, chunk_ticks)
+    covered), against ``engine``'s reference and writer rings.  A chunk
+    fails when its row does; the snapshot chunk when the state differs; the
+    last chunk when the final state breaks an invariant or holds a bad
+    line."""
+    ref_rows, ref_state, info = replay(engine.Reference, spec, seed,
+                                      snap_chunk, chunk_ticks)
     row_mis, fgap, bad = compare_rows(rows[:snap_chunk], ref_rows)
     st_mis, _ = compare_state(snapshot, ref_state)
-    inv, inv_bad = invariants(rows, final, spec, chunk_ticks)
+    inv, inv_bad = invariants(rows, final, spec, chunk_ticks,
+                              engine.writer_rings(spec))
     numbers = {
         "row_mismatch": row_mis,
         "float_gap": fgap,

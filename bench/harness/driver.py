@@ -1,11 +1,11 @@
 """Set up one cell, drive its timed window of chunks, and keep the record.
 
-A chunk is one call of the fused engine's jitted scan,
-``simulator._run_scan(cfg, chunk_ticks, state, chunk_ticks, "fused")``, with
-the state donated and carried from the previous chunk; it ends when its
-aggregated ``TickMetrics`` row is on the host.  Warm-up runs the same calls
-from ``init_sim`` before the window, so the window continues one simulation
-that starts at tick 0.
+A chunk is one call of the cell's engine's chunk program
+(``harness/engines/<engine>.py``: ``chunk_fn``), with the state donated and
+carried from the previous chunk; it ends when its aggregated ``TickMetrics``
+row is on the host.  Warm-up runs the same calls from the engine's initial
+state before the window, so the window continues one simulation that starts
+at tick 0.
 """
 from __future__ import annotations
 
@@ -83,44 +83,24 @@ def snapshot_chunk(cell, seed: int) -> int:
     return int(np.random.default_rng(seed % 2**63).integers(lo, hi))
 
 
-def build(cfg, seed: int):
-    """The initial state for the seed, built on the device.  The seed enters
-    as the initial PRNG key, ``jax.random.PRNGKey(seed)`` as in
-    ``init_sim``, so every seed shares one compiled program."""
-    import jax
-    from repro.core import simulator
-
-    @jax.jit
-    def init(key):
-        return dataclasses.replace(simulator.init_sim(cfg), rng=key)
-
-    return init(jax.random.PRNGKey(seed))
+def build(cell, cfg, seed: int, devices):
+    """The cell's initial state for the seed, which its engine builds on
+    the first ``cell.chips`` of ``devices``."""
+    return cell.engine.build(cfg, seed, devices[:cell.chips])
 
 
-def program_temp_bytes(cfg, chunk_ticks: int, state) -> int | None:
-    """The chunk program's temporaries, as XLA's memory analysis of the
-    compiled program gives them.  The allocator's peak does not show them;
-    they decide whether a size fits the chip.  Lowering again loads the
-    program from the compile cache, so only a traced run reads this, after
-    its window."""
-    from repro.core import simulator
-
-    compiled = simulator._run_scan.lower(cfg, chunk_ticks, state, chunk_ticks,
-                                         "fused").compile()
+def program_temp_bytes(cell, cfg, state) -> int | None:
+    """The chunk program's temporaries on a device, as XLA's memory analysis
+    of the compiled program gives them (an SPMD program's are per device).
+    The allocator's peak does not show them; they decide whether a size fits
+    the chip.  Lowering again loads the program from the compile cache, so
+    only a traced run reads this, after its window."""
+    compiled = cell.engine.lower(cfg, cell.chunk_ticks, state)
     analysis = compiled.memory_analysis()
     return None if analysis is None else int(analysis.temp_size_in_bytes)
 
 
-def chunk_fn(cfg, chunk_ticks: int):
-    from repro.core import simulator
-
-    def run(state):
-        return simulator._run_scan(cfg, chunk_ticks, state, chunk_ticks, "fused")
-
-    return run
-
-
-def drive(record: RunRecord, cfg, counter: CompileCounter, tracer=None):
+def drive(record: RunRecord, cfg, counter: CompileCounter, devices, tracer=None):
     """Warm up, then run the window: chunks back to back for ``seconds``,
     and on until the snapshot chunk if that comes later.  Returns (final
     state, the state after the snapshot chunk as host arrays).  The snapshot
@@ -128,11 +108,10 @@ def drive(record: RunRecord, cfg, counter: CompileCounter, tracer=None):
     its chunk's time includes the fetch."""
     import jax
 
-    from harness.check import state_leaves
-
     cell = record.cell
-    chunk = chunk_fn(cfg, cell.chunk_ticks)
-    state = build(cfg, record.seed)
+    engine = cell.engine
+    chunk = engine.chunk_fn(cfg, cell.chunk_ticks)
+    state = build(cell, cfg, record.seed, devices)
     jax.block_until_ready(state)
     record.mark("state_built")
     record.snap_chunk = snapshot_chunk(cell, record.seed)
@@ -163,7 +142,7 @@ def drive(record: RunRecord, cfg, counter: CompileCounter, tracer=None):
         with _annotate(tracer, "fetch"):
             record.rows.append(jax.device_get(row))
             if len(record.rows) == record.snap_chunk:
-                snapshot = state_leaves(state)
+                snapshot = engine.state_leaves(state)
         t_done = time.perf_counter()
         record.spans.append(("dispatch", c0, c1))
         record.spans.append(("fetch", c1, t_done))

@@ -6,9 +6,9 @@ returned unchanged, half of the nodes' cache work left out, a payload lane
 altered, a count altered, a modelled byte count altered.  ``wrong_victim``
 breaks the program itself: the upsert evicts the way after the least
 recently used one when a set is full, a fault that shows only once the
-caches have filled.  ``planted`` switches one on for the chunks that
-``driver.chunk_fn`` builds; the tests plant each at a small size, and
-``bench/control.py --fault`` at a cell's own size on the chip.
+caches have filled.  ``planted`` switches one on for the chunks that an
+engine module's ``chunk_fn`` builds; the tests plant each at a small size,
+and ``bench/control.py --fault`` at a cell's own size on the chip.
 """
 from __future__ import annotations
 
@@ -66,24 +66,23 @@ def _wrong_victim(real):
 
 
 @contextlib.contextmanager
-def planted(kind: str):
-    """Within the block, the chunks ``driver.chunk_fn`` builds carry the
-    fault ``kind``."""
+def planted(kind: str, engine):
+    """Within the block, the chunks that ``engine.chunk_fn`` builds (the
+    module a cell's ``engine`` is) carry the fault ``kind``."""
     import jax
 
-    from harness import driver
     from repro.core import flic
 
     if kind not in FAULTS:
         raise ValueError(f"unknown fault {kind!r}; known: {FAULTS}")
-    real_chunk, real_select = driver.chunk_fn, flic._select_way_rows
+    real_chunk, real_select = engine.chunk_fn, flic._select_way_rows
     if kind == "wrong_victim":
         flic._select_way_rows = _wrong_victim(real_select)
     else:
-        driver.chunk_fn = lambda cfg, k: _broken_chunk(kind, real_chunk(cfg, k))
+        engine.chunk_fn = lambda cfg, k: _broken_chunk(kind, real_chunk(cfg, k))
     jax.clear_caches()
     try:
         yield
     finally:
-        driver.chunk_fn, flic._select_way_rows = real_chunk, real_select
+        engine.chunk_fn, flic._select_way_rows = real_chunk, real_select
         jax.clear_caches()

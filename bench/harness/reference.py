@@ -479,7 +479,7 @@ class ReferenceSim:
         return agg
 
     def state(self) -> dict:
-        """The state, under the names ``harness.check.state_leaves`` uses."""
+        """The state, under the names an engine's ``state_leaves`` uses."""
         return {
             "caches.tags": self.tags, "caches.data_ts": self.data_ts,
             "caches.ins_ts": self.ins_ts, "caches.origin": self.origin,

@@ -7,11 +7,13 @@ Usage (from the root of a checkout, on a machine with the cell's chips)::
         --seconds 30 --trace 0
 
 The cell's configuration, traffic mix and run parameters are found by the
-names in ``BENCHMARK.json``.  Set-up builds the fog's state on the device
-from the seed and warms the cell's one chunk program; the window then runs
-chunks back to back for ``--seconds``.  Afterwards the plain reference
-replays the run from tick 0 past the caches' fill to a chunk drawn from the
-seed, and the whole run is checked (``correct``).
+names in ``BENCHMARK.json``, and the configuration's ``engine`` names the
+module ``bench/harness/engines/<engine>.py`` that runs it.  Set-up has that
+engine build the fog's state on the cell's chips from the seed and warms
+its one chunk program; the window then runs chunks back to back for
+``--seconds``, each one call of the engine's chunk program.  Afterwards the
+engine's plain reference replays the run from tick 0 past the caches' fill
+to a chunk drawn from the seed, and the whole run is checked (``correct``).
 With ``--trace 1`` the window runs under JAX's profiler and the line carries
 the per-layer metrics instead of the end-to-end ones.
 
@@ -91,21 +93,21 @@ def run(args, cell, devices, sim_overrides=None, t_start: float = T_START) -> di
                               t_start=t_start)
     tracer = Tracer() if args.trace else None
     record.mark("chip_ready")
-    final, snapshot = driver.drive(record, cfg, counter, tracer)
+    final, snapshot = driver.drive(record, cfg, counter, devices, tracer)
     used = devices[:cell.chips]
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in used]
     record.peak_bytes = max(p for p in peaks if p is not None) if any(
         p is not None for p in peaks) else None
     if tracer is not None:
         record.trace_summary = tracer.summary()
-        record.temp_bytes = driver.program_temp_bytes(cfg, cell.chunk_ticks, final)
+        record.temp_bytes = driver.program_temp_bytes(cell, cfg, final)
 
     t0 = time.perf_counter()
     rows = check.rows_to_host(record.rows)
-    fin = check.state_leaves(final)
+    fin = cell.engine.state_leaves(final)
     del final
     t1 = time.perf_counter()
-    numbers, failed, replay = check.verify(rows, snapshot, fin,
+    numbers, failed, replay = check.verify(cell.engine, rows, snapshot, fin,
                                            record.snap_chunk, spec, args.seed,
                                            cell.chunk_ticks)
     replay["fetch_s"] = t1 - t0

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Trace chunks of a cell and put their device time and idle gaps down to the
-fused tick's stages and the runtime's host work.
+tick's stages and the runtime's host work.
 
 Usage, on the chip::
 
@@ -14,12 +14,13 @@ window (``driver.drive``: chunks for that long and on to the snapshot
 chunk, whose state is fetched inside the window); ``--chunks`` traces just
 that many chunks, for a trace small enough to keep.  Either way the chunks
 run under JAX's profiler inside the benchmark's ``dispatch`` and ``fetch``
-spans; the chunk program's text comes from its compile after the window (a
-compile-cache load), and ``harness.stages`` reduces the trace with it.  One
-JSON line gives the metrics of ``bench/metrics/`` that read the trace,
-``breakdown.stages``, the idle gaps labelled ``<span>/<event>`` and the
-seconds each step of the reduction took.  ``--save PREFIX`` keeps the trace
-as ``PREFIX.xplane.pb`` and the program's text as ``PREFIX.hlo.txt.gz``.
+spans; the chunk program's text comes from the cell's engine's compile of
+it after the window (a compile-cache load), and ``harness.stages`` reduces
+the trace with it.  One JSON line gives the metrics of ``bench/metrics/``
+that read the trace, ``breakdown.stages``, the idle gaps labelled
+``<span>/<event>`` and the seconds each step of the reduction took.
+``--save PREFIX`` keeps the trace as ``PREFIX.xplane.pb`` and the program's
+text as ``PREFIX.hlo.txt.gz``.
 
 ``run_cell.py`` does not run this.  Without a TPU it exits 2.
 """
@@ -63,10 +64,11 @@ def traced_window(cell, cfg, args):
     if args.seconds is not None:
         record = driver.RunRecord(cell=cell, seed=args.seed, seconds=args.seconds,
                                   t_start=time.perf_counter())
-        state, _ = driver.drive(record, cfg, driver.CompileCounter(), tracer)
+        state, _ = driver.drive(record, cfg, driver.CompileCounter(),
+                                jax.devices(), tracer)
         return state, tracer
-    chunk = driver.chunk_fn(cfg, cell.chunk_ticks)
-    state = driver.build(cfg, args.seed)
+    chunk = cell.engine.chunk_fn(cfg, cell.chunk_ticks)
+    state = driver.build(cell, cfg, args.seed, jax.devices())
     for _ in range(cell.warm_ticks // cell.chunk_ticks):
         state, row = chunk(state)
         jax.device_get(row)
@@ -87,7 +89,6 @@ def main(argv=None) -> int:
     keep_logs_in_tmpdir()
     import jax
     from harness import cells, driver, stages, trace
-    from repro.core import simulator
 
     cell = cells.load_cell(args.workload)
     missing = chips_missing(jax.devices(), cell.chips)
@@ -105,8 +106,7 @@ def main(argv=None) -> int:
 
     secs = {}
     t0 = time.perf_counter()
-    compiled = simulator._run_scan.lower(cfg, cell.chunk_ticks, state,
-                                         cell.chunk_ticks, "fused").compile()
+    compiled = cell.engine.lower(cfg, cell.chunk_ticks, state)
     t1 = time.perf_counter()
     text = compiled.as_text()
     secs["compile"], secs["as_text"] = t1 - t0, time.perf_counter() - t1

@@ -1,5 +1,5 @@
-"""The window's chunks continue one simulation: chunked ``_run_scan`` calls
-from ``init_sim`` give the rows of one ``run_sim`` with
+"""The window's chunks continue one simulation: the chunk calls of a cell's
+engine from its initial state give the rows of one ``run_sim`` with
 ``metrics_every=chunk_ticks``, bit for bit, and the same final state."""
 import jax
 import numpy as np
@@ -19,8 +19,8 @@ def test_chunked_scan_equals_one_run(name):
     cfg = cells.sim_config(cell, n_nodes=N_SMALL)
     chunks, k = 5, max(cell.chunk_ticks, 2)
     seed = 2**31 + 11
-    state = driver.build(cfg, seed)
-    step = driver.chunk_fn(cfg, k)
+    state = driver.build(cell, cfg, seed, jax.devices())
+    step = cell.engine.chunk_fn(cfg, k)
     rows = []
     for _ in range(chunks):
         state, row = step(state)

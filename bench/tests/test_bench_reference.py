@@ -28,8 +28,7 @@ def test_control_is_not_correct(name):
     cell = cells.load_cell(name)
     spec = cells.spec_with(cell, n_nodes=48)
     for seed in (1, 2, 3):
-        numbers = control.control_numbers(spec, seed, cell.ref_chunks[0],
-                                          cell.chunk_ticks)
+        numbers = control.control_numbers(cell, spec, seed, cell.ref_chunks[0])
         assert not check.correct(numbers), numbers
         assert numbers["state_mismatch"] > 0 and numbers["bad_lines"] > 0
         assert numbers["float_gap"] > check.LIMITS["float_gap"]

@@ -60,7 +60,7 @@ def test_sound_run_is_correct(name):
 @pytest.mark.parametrize("name", CELLS)
 @pytest.mark.parametrize("kind", faults.FAULTS)
 def test_broken_timed_path_is_not_correct(name, kind):
-    with faults.planted(kind):
+    with faults.planted(kind, cells.load_cell(name).engine):
         line = _run(name)
     assert line["correct"] is False, line["checks"]
     assert line["failed"] > 0
